@@ -85,7 +85,8 @@ def _bench_mesh_srht(quick: bool) -> dict:
         n, d, m, q = {n}, 64, 512, 8
         A = jax.random.normal(jax.random.PRNGKey(0), (n, d), jnp.float32)
         keys = prng.worker_keys(jax.random.PRNGKey(1), q)
-        mesh = jax.make_mesh((8,), ("workers",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("workers",))
         spec = sk.SketchSpec("srht", m)
 
         def timeit(fn, repeat=5):
@@ -106,7 +107,8 @@ def _bench_mesh_srht(quick: bool) -> dict:
                            "mesh_forced_s": t_mesh, "auto_s": t_auto, "loop_s": t_loop}}))
         """
     )
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    # The child runs on the CPU: the parent has imported JAX, and on a chip it holds it.
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"), JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True, timeout=900, env=env
     )

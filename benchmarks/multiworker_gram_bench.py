@@ -98,7 +98,8 @@ def _bench_reduced_rounds(shape: dict, repeat: int) -> dict:
         print(json.dumps({{"rounds20_s": t20, "rounds8_s": t8, "speedup": t20 / t8}}))
         """
     )
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    # The child runs on the CPU: the parent has imported JAX, and on a chip it holds it.
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"), JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True, timeout=1800, env=env
     )

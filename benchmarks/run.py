@@ -14,6 +14,7 @@ import time
 import traceback
 
 from repro.analysis.annotations import sanctioned_wall_timer
+from repro.utils import env as envcfg
 
 from benchmarks import (
     bias_bounds,
@@ -78,6 +79,7 @@ def main(argv=None) -> int:
             file=sys.stderr,
         )
         return 2
+    envcfg.configure_compile_cache()
     failures = []
     for k in keys:
         mod = MODULES[k]

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import averaging, distributed, privacy, sketches as sk, solve, theory
 from repro.data import student_t_regression
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -37,7 +38,7 @@ def main():
     )
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     rounds = max(1, args.workers // n_dev)
     q = n_dev * rounds
     print(f"devices={n_dev} rounds={rounds} -> q={q} workers, sketch={args.sketch} m={m}")

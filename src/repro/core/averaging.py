@@ -15,7 +15,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.utils import compat
 
 
 def worker_index(axis_names) -> jax.Array:
@@ -26,7 +25,7 @@ def worker_index(axis_names) -> jax.Array:
     """
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * compat.axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

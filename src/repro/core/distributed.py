@@ -40,7 +40,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import averaging, operators, sketches as sk, solve
 from repro.utils import prng
-from repro.utils.compat import shard_map
+
+# Every worker body below may run a Pallas kernel (``spec.use_kernel``), whose
+# outputs carry no varying-mesh-axis type; the shard_maps therefore run with
+# ``check_vma=False``.
 
 
 _worker_index = averaging.worker_index
@@ -48,13 +51,6 @@ _worker_index = averaging.worker_index
 # Incremented each time the multiround worker body is traced; tests assert the
 # jitted closure is hoisted out of the round loop (one trace per call, not per round).
 MULTIROUND_TRACE_COUNT = 0
-
-
-def _mesh_workers(mesh: Mesh, axis_names: tuple) -> int:
-    q = 1
-    for name in axis_names:
-        q *= mesh.shape[name]
-    return q
 
 
 def _checked_mask(straggler_mask: Optional[jax.Array], q: int) -> jax.Array:
@@ -102,7 +98,7 @@ def distributed_sketch_solve(
     Returns:
       x̄ (d,), replicated.
     """
-    q = _mesh_workers(mesh, axis_names)
+    q = operators.mesh_world(mesh, axis_names)
     straggler_mask = _checked_mask(straggler_mask, q)
 
     a_spec = P(axis_names) if row_sharded else P()
@@ -115,7 +111,7 @@ def distributed_sketch_solve(
         xk = solve.sketch_and_solve(spec, wkey, A_blk, b_blk, reg=reg, method=method)
         return averaging.psum_average(xk, mask_all[widx], axis_names, on_empty=on_empty)
 
-    fn = shard_map(worker, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(worker, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return fn(key, A, b, straggler_mask)
 
 
@@ -148,7 +144,7 @@ def distributed_sketch_solve_master(
     :func:`distributed_sketch_solve`, so the two modes return the same x̄ for the
     same inputs (up to the solver's float tolerance).
     """
-    q = _mesh_workers(mesh, axis_names)
+    q = operators.mesh_world(mesh, axis_names)
     straggler_mask = _checked_mask(straggler_mask, q)
 
     keys = prng.worker_keys(key, q, round_id)
@@ -165,11 +161,12 @@ def distributed_sketch_solve_master(
                 xk, mask_all[widx], axis_names, on_empty=on_empty
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             worker_fused,
             mesh=mesh,
             in_specs=(P(axis_names), P(axis_names), P()),
             out_specs=P(),
+            check_vma=False,
         )
         return fn(Gs, cs, straggler_mask)
 
@@ -182,11 +179,12 @@ def distributed_sketch_solve_master(
         xk = solve.lstsq(SA_blk[0], Sb_blk[0], reg=reg, method=method)
         return averaging.psum_average(xk, mask_all[widx], axis_names, on_empty=on_empty)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(P(axis_names), P(axis_names), P()),
         out_specs=P(),
+        check_vma=False,
     )
     return fn(SA, Sb, straggler_mask)
 
@@ -204,7 +202,7 @@ def distributed_sketch_least_norm(
     on_empty: str = "nan",
 ):
     """§V right-sketch averaging over the mesh (n < d). A replicated."""
-    q = _mesh_workers(mesh, axis_names)
+    q = operators.mesh_world(mesh, axis_names)
     straggler_mask = _checked_mask(straggler_mask, q)
 
     def worker(key, A_rep, b_rep, mask_all):
@@ -213,7 +211,7 @@ def distributed_sketch_least_norm(
         xk = solve.sketch_least_norm(spec, wkey, A_rep, b_rep)
         return averaging.psum_average(xk, mask_all[widx], axis_names, on_empty=on_empty)
 
-    fn = shard_map(worker, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P())
+    fn = jax.shard_map(worker, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P(), check_vma=False)
     return fn(key, A, b, straggler_mask)
 
 
@@ -229,8 +227,8 @@ def _multiround_fn(mesh, spec, axis_names, reg, method, on_empty):
         xk = solve.sketch_and_solve(spec, wkey, A_rep, b_rep, reg=reg, method=method)
         return averaging.psum_average(xk, mask_all[widx], axis_names, on_empty=on_empty)
 
-    fn = shard_map(
-        worker, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P()
+    fn = jax.shard_map(
+        worker, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P(), check_vma=False
     )
     return jax.jit(fn)
 
@@ -268,7 +266,7 @@ def distributed_sketch_solve_multiround(
     grid, but arrival-ordered streaming averaging, deadlines, retries, and early
     stopping instead of the synchronous wave barrier. Returns x̄ either way.
     """
-    q = _mesh_workers(mesh, axis_names)
+    q = operators.mesh_world(mesh, axis_names)
     if latency is not None:
         from repro import runtime as rt
 

@@ -266,16 +266,12 @@ class SketchOp:
     def build(cls, spec, key, n, *, scores=None) -> "SketchOp":
         raise NotImplementedError
 
-    @classmethod
-    def gram_batched_kernel(cls, spec, keys, A, b):
-        """All ``q`` workers' joint Grams ``(G_k, c_k)`` from ONE fused kernel
-        launch over ONE read of A — the multi-worker form of the kernel-routed
-        :meth:`gram_blocked`. Returns ``NotImplemented`` when the kind has no
-        multi-worker kernel; :func:`gram_batched` then falls back to per-key
-        dispatch. Worker slice ``w`` must be bitwise-identical to the per-key
-        kernel path under ``keys[w]``.
-        """
-        return NotImplemented
+    # Kinds with a multi-worker fused gram kernel override this with a classmethod
+    # ``(spec, keys, A, b) -> (Gs, cs)``: all ``q`` workers' joint Grams from one
+    # kernel pass over A, worker slice ``w`` bitwise-identical to the per-key
+    # kernel path under ``keys[w]``. Kinds without one (None) always take the
+    # per-key route in :func:`gram_batched`.
+    gram_batched_kernel = None
 
     # -- required tile primitive --------------------------------------------------
 
@@ -915,7 +911,7 @@ def gram_blocked_host(
 # ------------------------------------------------------- multi-worker batching
 
 
-def _mesh_world(mesh, axis_names) -> int:
+def mesh_world(mesh, axis_names) -> int:
     q = 1
     for name in axis_names:
         q *= mesh.shape[name]
@@ -936,6 +932,16 @@ def _mesh_batch_enabled() -> bool:
     if forced is not None:
         return forced
     return jax.default_backend() != "cpu"
+
+
+def _mesh_shards_keys(mesh, axis_names, q: int) -> bool:
+    """Whether batched dispatch shards the q worker keys over ``mesh``: only a mesh
+    of more than one worker shard, dividing q, on a backend where sharding pays
+    (:func:`_mesh_batch_enabled`). A one-device mesh shards nothing."""
+    if mesh is None or not _mesh_batch_enabled():
+        return False
+    world = mesh_world(mesh, axis_names)
+    return world > 1 and q % world == 0
 
 
 def _batched_prefers_loop(spec: sk.SketchSpec) -> bool:
@@ -962,23 +968,20 @@ def _batched_over_keys(per_key, keys: jax.Array, spec: sk.SketchSpec, mesh, axis
     fallback under the same keys), else the per-backend loop/vmap choice of
     :func:`_batched_prefers_loop`.
     """
-    if mesh is not None and _mesh_batch_enabled():
-        world = _mesh_world(mesh, axis_names)
-        if world > 1 and keys.shape[0] % world == 0:
-            from jax.sharding import PartitionSpec as P
+    if _mesh_shards_keys(mesh, axis_names, keys.shape[0]):
+        from jax.sharding import PartitionSpec as P
 
-            from repro.utils.compat import shard_map
+        def worker(keys_blk, *ex):
+            return jax.lax.map(lambda k: per_key(k, *ex), keys_blk)
 
-            def worker(keys_blk, *ex):
-                return jax.lax.map(lambda k: per_key(k, *ex), keys_blk)
-
-            fn = shard_map(
-                worker,
-                mesh=mesh,
-                in_specs=(P(axis_names),) + tuple(P() for _ in extras),
-                out_specs=P(axis_names),
-            )
-            return fn(keys, *extras)
+        fn = jax.shard_map(
+            worker,
+            mesh=mesh,
+            in_specs=(P(axis_names),) + tuple(P() for _ in extras),
+            out_specs=P(axis_names),
+            check_vma=False,  # per_key may run a Pallas kernel (untyped mesh variance)
+        )
+        return fn(keys, *extras)
     if _batched_prefers_loop(spec):
         return jax.lax.map(lambda k: per_key(k, *extras), keys)
     return jax.vmap(lambda k: per_key(k, *extras))(keys)
@@ -1033,15 +1036,17 @@ def gram_batched(
     ``(Gs, cs)`` of shapes (q, d, d) and (q, d[, k]); ``cs`` is None when b is.
 
     Kernel-routed kinds with a multi-worker kernel (gaussian/rademacher/sjlt/srht)
-    take :meth:`SketchOp.gram_batched_kernel` when no mesh is sharding the keys:
-    ONE launch / ONE read of A for all q sketches instead of q kernel launches,
-    bitwise-identical per worker to the per-key loop.
+    take :meth:`SketchOp.gram_batched_kernel` when no mesh is sharding the keys
+    (no mesh, or a one-shard mesh such as one chip): one kernel pass over A for
+    all q sketches instead of q kernel launches, bitwise-identical per worker to
+    the per-key loop. Which kinds have that kernel is fixed by their class, not
+    decided at run time.
     """
     scores = _scores_for(spec, A, scores)
-    if spec.use_kernel and (mesh is None or not _mesh_batch_enabled()):
-        fused = _REGISTRY[spec.kind].gram_batched_kernel(spec, keys, A, b)
-        if fused is not NotImplemented:
-            return fused
+    fused = _REGISTRY[spec.kind].gram_batched_kernel
+    sharded = _mesh_shards_keys(mesh, axis_names, keys.shape[0])
+    if spec.use_kernel and fused is not None and not sharded:
+        return fused(spec, keys, A, b)
     n = A.shape[0]
     extras = (A,) + (() if b is None else (b,)) + ((scores,) if scores is not None else ())
 

@@ -140,8 +140,10 @@ def sketch_least_norm(
 
 
 def residual_cost(A: jax.Array, b: jax.Array, x: jax.Array) -> jax.Array:
-    """f(x) = ‖Ax − b‖²."""
-    r = A @ x - b
+    """f(x) = ‖Ax − b‖², with ``Ax`` at f32 precision: the residual is a small
+    difference of large terms, and a bf16-pass product (the TPU default) would
+    swamp the excess cost the paper's error metric measures."""
+    r = jnp.matmul(A, x, precision=jax.lax.Precision.HIGHEST) - b
     return jnp.vdot(r, r).real
 
 

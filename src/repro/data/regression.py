@@ -13,6 +13,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+T_BLOCK_ROWS = 2**14  # student-t rows drawn per block (bounds the sampler's temporaries)
+
 
 def gaussian_regression(key, n: int, d: int, *, noise: float = 0.1, planted: bool = True):
     ka, kx, ke = jax.random.split(key, 3)
@@ -27,11 +29,21 @@ def gaussian_regression(key, n: int, d: int, *, noise: float = 0.1, planted: boo
 
 
 def student_t_regression(key, n: int, d: int, *, df: float = 1.5, noise: float = 0.1):
-    """Paper Fig. 3: A entries ~ student-t(df) (heavy-tailed, high row-coherence)."""
+    """Paper Fig. 3: A entries ~ student-t(df) (heavy-tailed, high row-coherence).
+
+    A is drawn in row blocks of ``min(n, T_BLOCK_ROWS)`` (block i from
+    ``fold_in(key_A, i)``) so the t sampler's rejection-loop temporaries scale with a
+    block, not with A: at n=2^20, d=1000 a one-shot draw needs ~76 GB of device memory.
+    """
     ka, kx, ke = jax.random.split(key, 3)
-    A = jax.random.t(ka, df, (n, d))
-    # clip the extreme tail so f(x*) is finite-variance enough for Monte Carlo runs
-    A = jnp.clip(A, -1e3, 1e3)
+    rows = min(n, T_BLOCK_ROWS)
+    nb = -(-n // rows)
+
+    def draw(i):
+        # clip the extreme tail so f(x*) is finite-variance enough for Monte Carlo runs
+        return jnp.clip(jax.random.t(jax.random.fold_in(ka, i), df, (rows, d)), -1e3, 1e3)
+
+    A = jax.lax.map(draw, jnp.arange(nb)).reshape(nb * rows, d)[:n]
     x = jax.random.normal(kx, (d,))
     b = A @ x + noise * jax.random.normal(ke, (n,))
     return A, b, {"x_truth": x}

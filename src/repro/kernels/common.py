@@ -85,8 +85,15 @@ def threefry2x32(
 
 
 def bits_to_open_unit(bits: jax.Array) -> jax.Array:
-    """uint32 -> float32 in (0, 1), strictly positive so log() is finite."""
-    return (bits.astype(jnp.float32) + 0.5) * jnp.float32(2.0**-32)
+    """uint32 -> float32 in (0, 1), strictly positive so log() is finite.
+
+    Mosaic has no uint32 -> float32 cast, so the word is converted as two exact
+    16-bit int32 halves; ``hi·2¹⁶ + lo`` is rounded once, which is the same f32 a
+    direct cast gives, so every consumer draws the values it always drew.
+    """
+    hi = (bits >> np.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & np.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return (hi * jnp.float32(65536.0) + lo + 0.5) * jnp.float32(2.0**-32)
 
 
 def counter_normal(k0, k1, c0, c1, *, rounds: int | None = None):

@@ -2,145 +2,72 @@
 
 The FWHT formulation of the SRHT needs the whole (padded) column dimension resident
 before any output row is final — it cannot stream row tiles of A. The streaming form
-instead materializes S *tiles* directly from the Sylvester closed form
+instead makes S *tiles* directly from the Sylvester closed form
 
     S[r, j] = (1/√m) · (−1)^popcount(rows[r] & j) · D[j]
 
-(a popcount + sign per element — no transform, no HBM traffic for S) and follows the
-same single-pass recipe as the Gaussian/SJLT gram kernels: grid over row tiles of A,
-an (m, d) VMEM scratch accumulator across the sequential grid, and one tiny (d, d)
-contraction at the final step. Per element this costs an AND + popcount versus the
-FWHT's log n adds; for the paper's m = O(d) ≪ n regime both paths are dominated by
-streaming A, and only this form never needs all of A at once.
+(an AND + popcount per element — no transform, no HBM traffic for S) and runs on
+:mod:`repro.kernels.fused_gram`'s grid like every other family. For the paper's
+m = O(d) ≪ n regime both forms are dominated by the matmul with A, and only this one
+never needs all of A at once.
 
-The sampled-row ids arrive padded with −1 (masked in-kernel), so ``m`` need not be a
-multiple of the sublane tiling.
+The sampled-row ids arrive as (q, m_pad, 1) int32 padded with −1 (masked in-kernel),
+one (bm, 1) column block per m block. VMEM at the chip smoke's shapes (d_pad=1024,
+m=10000): the shared kernel's 12 MiB per worker and 5.25 MiB fixed, plus 1 MiB per worker
+for the lane-padded row-id blocks — q=8 runs as two launches of 4 workers,
+57.25 MiB each.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
+from repro.kernels import common, fused_gram
+
+# A (bm, 1) int32 block is lane-padded to (bm, 128) in VMEM, and double-buffered.
+ROW_ID_BYTES = 2 * 4 * 128
 
 
 def srht_gram_tiles(
     A: jax.Array,
     rows: jax.Array,
     key_words: jax.Array,
+    m: int,
+    p: fused_gram.Plan,
     *,
-    block_n: int,
-    inv_sqrt_m: float,
     interpret: bool = True,
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) for the SRHT with sampled Hadamard rows ``rows`` and Rademacher
-    diagonal keyed by ``key_words``. A: (n_pad, d_pad) zero-padded; rows: (m_pad, 1)
-    int32, padded entries −1. Returns (d_pad, d_pad) f32."""
-    n, d = A.shape
-    m_pad = rows.shape[0]
-    n_tiles = n // block_n
+    """All q workers' SRHT Grams from one launch. ``A``: (n_pad, d_pad) zero-padded;
+    ``rows``: (q, m_pad, 1) int32 sampled Hadamard rows, −1 padding; ``key_words``:
+    (q, 2) uint32 Rademacher-diagonal keys. Returns (q, d_pad, d_pad) f32; worker w
+    is bitwise equal to a one-worker launch with its rows and key."""
+    q = key_words.shape[0]
+    inv_sqrt_m = 1.0 / math.sqrt(m)
 
-    def kernel(kw_ref, r_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        k0 = kw_ref[0]
-        k1 = kw_ref[1]
-        r = r_ref[...]  # (m_pad, 1) int32, −1 marks padding
-        j = (ni * block_n).astype(jnp.uint32) + jax.lax.broadcasted_iota(
-            jnp.uint32, (1, block_n), 1
+    def s_tile(refs, w, r0, rl, c0):
+        kw_ref, r_ref = refs
+        r = r_ref[w, pl.ds(rl, p.gen), :]  # (gen, 1), −1 marks padding
+        j = jnp.asarray(c0).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+            jnp.uint32, (1, p.bn), 1
         )
-        parity = jax.lax.population_count(r.astype(jnp.uint32) & j)  # (m_pad, block_n)
+        parity = jax.lax.population_count(r.astype(jnp.uint32) & j)  # (gen, bn)
         h = (1 - 2 * (parity & jnp.uint32(1)).astype(jnp.int32)).astype(jnp.float32)
-        dsign = common.counter_rademacher(k0, k1, j, jnp.uint32(0))  # (1, block_n)
-        s_tile = jnp.where(r >= 0, h * dsign * jnp.float32(inv_sqrt_m), 0.0)
-        acc_ref[...] += jnp.dot(s_tile, a_ref[...], preferred_element_type=jnp.float32)
+        dsign = common.counter_rademacher(kw_ref[w, 0], kw_ref[w, 1], j, jnp.uint32(0))
+        return jnp.where(r >= 0, h * dsign * jnp.float32(inv_sqrt_m), 0.0)
 
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            acc = acc_ref[...]
-            o_ref[...] = jax.lax.dot_general(
-                acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda ni: (0,)),
-            pl.BlockSpec((m_pad, 1), lambda ni: (0, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
+    return fused_gram.gram_multi(
+        A,
+        [
+            (key_words, pl.BlockSpec(memory_space=pltpu.SMEM)),
+            (rows, pl.BlockSpec((q, p.bm, 1), lambda mb, ni: (0, mb, 0))),
         ],
-        out_specs=pl.BlockSpec((d, d), lambda ni: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad, d), jnp.float32)],
+        s_tile,
+        q,
+        p,
+        name="srht_gram",
         interpret=interpret,
-    )(key_words, rows, A)
-
-
-def srht_gram_tiles_multi(
-    A: jax.Array,
-    rows: jax.Array,
-    key_words: jax.Array,
-    *,
-    block_n: int,
-    inv_sqrt_m: float,
-    interpret: bool = True,
-) -> jax.Array:
-    """All q workers' SRHT Grams from ONE launch / ONE read of A.
-
-    ``rows``: (q, m_pad, 1) sampled Hadamard row ids (−1 padding); ``key_words``:
-    (q, 2) Rademacher-diagonal keys. The Hadamard column-index row ``j`` is built
-    once per grid step; the popcount parity, diagonal signs, and scatter matmul
-    run per worker in a static unroll. Output slice w is bitwise equal to a
-    single :func:`srht_gram_tiles` launch for worker w.
-    """
-    n, d = A.shape
-    q, m_pad, _ = rows.shape
-    n_tiles = n // block_n
-
-    def kernel(kw_ref, r_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        a = a_ref[...]
-        j = (ni * block_n).astype(jnp.uint32) + jax.lax.broadcasted_iota(
-            jnp.uint32, (1, block_n), 1
-        )
-        for w in range(q):
-            r = r_ref[w]  # (m_pad, 1) int32, −1 marks padding
-            parity = jax.lax.population_count(r.astype(jnp.uint32) & j)
-            h = (1 - 2 * (parity & jnp.uint32(1)).astype(jnp.int32)).astype(jnp.float32)
-            dsign = common.counter_rademacher(kw_ref[w, 0], kw_ref[w, 1], j, jnp.uint32(0))
-            s_tile = jnp.where(r >= 0, h * dsign * jnp.float32(inv_sqrt_m), 0.0)
-            acc_ref[w] += jnp.dot(s_tile, a, preferred_element_type=jnp.float32)
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            for w in range(q):
-                acc = acc_ref[w]
-                o_ref[w] = jax.lax.dot_general(
-                    acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, 2), lambda ni: (0, 0)),
-            pl.BlockSpec((q, m_pad, 1), lambda ni: (0, 0, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, d, d), lambda ni: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((q, m_pad, d), jnp.float32)],
-        interpret=interpret,
-    )(key_words, rows, A)
+    )

@@ -11,16 +11,15 @@ are exactly the pass-1 tiles.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common
+from repro.kernels import common, fused_gram
 from repro.kernels.fwht import gram as K_gram
 from repro.kernels.fwht import kernel as K
 
-MAX_TILE_ROWS = 4096  # 4096×256 f32 tile = 4 MiB — well inside a v5e core's ~16 MiB more VMEM
+MAX_TILE_ROWS = 4096  # 4096×256 f32 FWHT tile = 4 MiB of VMEM
 DEFAULT_BLOCK_D = 256
 
 
@@ -64,43 +63,24 @@ def fwht(x: jax.Array, *, block_d: int = DEFAULT_BLOCK_D, interpret: bool | None
     return y[:, :d].astype(dtype) if orig_ndim == 2 else y[:, 0].astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def srht_gram(
     A: jax.Array, rows: jax.Array, key_words: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
     """G = (SA)ᵀ(SA) for the SRHT in one fused streamed pass (no FWHT, no SA in HBM).
 
-    ``A``: (n, d) *already sign-flipped is NOT expected* — the Rademacher diagonal D
-    keyed by ``key_words`` is applied inside the kernel via the Sylvester closed form.
-    ``rows``: (m,) sampled Hadamard row ids. Returns (d, d) f32.
+    ``A``: (n, d), not sign-flipped — the Rademacher diagonal D keyed by
+    ``key_words`` is applied inside the kernel via the Sylvester closed form.
+    ``rows``: (m,) sampled Hadamard row ids. Returns (d, d) f32. The one-worker
+    case of :func:`srht_gram_multi`.
     """
-    interpret = common.resolve_interpret(interpret)
-    n, d = A.shape
-    m = rows.shape[0]
-    bn = min(MAX_TILE_ROWS, common.round_up(n, 8))
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
-    rows_p = (common.pad_axis_to(rows.astype(jnp.int32) + 1, 0, m_pad) - 1).reshape(m_pad, 1)
-
-    G = K_gram.srht_gram_tiles(
-        Af,
-        rows_p,
-        key_words,
-        block_n=bn,
-        inv_sqrt_m=1.0 / math.sqrt(m),
-        interpret=interpret,
-    )
-    return G[:d, :d]
+    return srht_gram_multi(A, rows[None], key_words[None], interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def srht_gram_multi(
     A: jax.Array, rows: jax.Array, key_words: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
-    """All q workers' SRHT Grams from ONE launch / ONE read of A.
+    """All q workers' SRHT Grams, one read of A per launch.
 
     ``rows``: (q, m) per-worker sampled Hadamard rows; ``key_words``: (q, 2)
     diagonal keys. Returns (q, d, d) f32, slice w bitwise-identical to
@@ -109,21 +89,15 @@ def srht_gram_multi(
     interpret = common.resolve_interpret(interpret)
     n, d = A.shape
     q, m = rows.shape
-    bn = min(MAX_TILE_ROWS, common.round_up(n, 8))
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
-    rows_p = (common.pad_axis_to(rows.astype(jnp.int32) + 1, 1, m_pad) - 1).reshape(q, m_pad, 1)
-
-    G = K_gram.srht_gram_tiles_multi(
-        Af,
-        rows_p,
-        key_words,
-        block_n=bn,
-        inv_sqrt_m=1.0 / math.sqrt(m),
-        interpret=interpret,
+    p = fused_gram.plan(q, m, n, d, per_row_bytes=K_gram.ROW_ID_BYTES)
+    Af = fused_gram.pad_data(A, p)
+    rows_p = (common.pad_axis_to(rows.astype(jnp.int32) + 1, 1, p.m_pad) - 1)[..., None]
+    G = fused_gram.chunked(
+        lambda s, k: K_gram.srht_gram_tiles(
+            Af, rows_p[s : s + k], key_words[s : s + k], m, p, interpret=interpret
+        ),
+        q,
+        p,
     )
     return G[:, :d, :d]
 

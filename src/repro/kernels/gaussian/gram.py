@@ -1,152 +1,63 @@
-"""Pallas TPU kernel: fused Gaussian sketch→Gram — G = (SA)ᵀ(SA) in ONE pass over A.
+"""Pallas TPU kernels: fused Gaussian sketch→Gram, and the Gaussian adjoint.
 
-The sketch-and-solve hot loop only ever consumes ``SA`` through its Gram matrix
-``G = (SA)ᵀ(SA)`` and right-hand side ``c = (SA)ᵀ(Sb)`` (the m×d problem is solved by
-Cholesky on G). Materializing SA first means a full HBM round-trip of an (m, d) array
-per worker plus a second kernel launch for the Gram; materializing S itself is O(m·n)
-bytes of pure reproducible noise.
+The gram kernel never materializes S or SA: :mod:`repro.kernels.fused_gram` walks
+row tiles of A over an m-blocked grid, and this module supplies the S tile —
+i.i.d. N(0, 1/m) from the counter RNG (``common.counter_normal`` at global
+(row, column)), the same stream as ``GaussianOp.columns`` and the apply kernel.
+Per entry that is one 20-round threefry plus Box-Muller on the VPU against 2·d
+MXU flops. Sketching ``[A | b]`` jointly yields G and c from the same pass.
 
-This kernel does the whole chain in one streamed pass: the grid walks row tiles of A,
-each (m, block_n) tile of S is generated in VMEM from the counter RNG (same stream as
-``GaussianOp.columns`` / the apply kernel), contracted with the A tile on the MXU into
-an (m, d) VMEM scratch accumulator — scratch persists across the sequential TPU grid —
-and only at the final grid step is the tiny (d, d) Gram contraction formed and written
-out. HBM traffic: read A once, write d² floats. S and SA never exist in HBM.
-
-Sketching ``[A | b]`` jointly yields G and c from the same pass (callers slice).
+VMEM at the chip smoke's shapes (d_pad=1024, m=10000): the shared kernel's figures
+(12 MiB per worker, 5.25 MiB fixed; the (q, 2) key words sit in SMEM) — q=8
+runs as two launches of 4 workers, 53.25 MiB each.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
+from repro.kernels import common, fused_gram
 
 
 def gaussian_gram_tiles(
     A: jax.Array,
     key_words: jax.Array,
     m: int,
-    m_pad: int,
+    p: fused_gram.Plan,
     *,
-    block_n: int,
-    inv_sqrt_m: float,
     interpret: bool = True,
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) with S ~ N(0, 1/m) generated in-core. A: (n_pad, d_pad), both
-    padded dims zero-filled; returns (d_pad, d_pad) f32. Rows of S beyond ``m``
-    (padding to the sublane multiple) are masked to zero so they never enter G."""
-    n, d = A.shape
-    n_tiles = n // block_n
+    """All q workers' Grams ``(S_w A)ᵀ(S_w A)`` with S ~ N(0, 1/m) made in-core.
 
-    def kernel(kw_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        k0 = kw_ref[0]
-        k1 = kw_ref[1]
-        rows = jax.lax.broadcasted_iota(jnp.uint32, (m_pad, block_n), 0)
-        cols = (ni * block_n).astype(jnp.uint32) + jax.lax.broadcasted_iota(
-            jnp.uint32, (m_pad, block_n), 1
-        )
-        s_tile = common.counter_normal(k0, k1, rows, cols) * jnp.float32(inv_sqrt_m)
-        s_tile = jnp.where(rows < jnp.uint32(m), s_tile, 0.0)
-        acc_ref[...] += jnp.dot(s_tile, a_ref[...], preferred_element_type=jnp.float32)
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            acc = acc_ref[...]
-            o_ref[...] = jax.lax.dot_general(
-                acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda ni: (0,)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((d, d), lambda ni: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad, d), jnp.float32)],
-        interpret=interpret,
-    )(key_words, A)
-
-
-def gaussian_gram_tiles_multi(
-    A: jax.Array,
-    key_words: jax.Array,
-    m: int,
-    m_pad: int,
-    *,
-    block_n: int,
-    inv_sqrt_m: float,
-    interpret: bool = True,
-) -> jax.Array:
-    """All q workers' Grams from ONE kernel launch / ONE read of A.
-
-    ``key_words``: (q, 2) uint32 — one counter key per worker. The grid still
-    walks row tiles of A, but each step contracts the tile against all q workers'
-    S tiles (statically unrolled: q is a trace-time constant, so every scratch
-    access is static — no dynamic VMEM indexing) into a (q, m_pad, d) scratch.
-    The A tile's index map depends only on the grid step, so it is fetched once
-    per step and reused across workers — the per-worker launch loop read A q
-    times. Per worker the op sequence (same tile order, same dot shapes) is
-    identical to :func:`gaussian_gram_tiles`, so the (d_pad, d_pad) slices of the
-    (q, d_pad, d_pad) output are bitwise equal to q single launches.
-
-    VMEM budget: scratch is q·m_pad·d·4 bytes (q=8, m=1024, d=257-pad → ~8 MiB on
-    the acceptance shape) — callers chunk q when the budget doesn't fit.
+    ``A``: (n_pad, d_pad) zero-padded; ``key_words``: (q, 2) uint32, one counter key
+    per worker. Returns (q, d_pad, d_pad) f32; worker w is bitwise equal to a
+    one-worker launch with ``key_words[w:w+1]``.
     """
-    n, d = A.shape
     q = key_words.shape[0]
-    n_tiles = n // block_n
+    inv_sqrt_m = 1.0 / math.sqrt(m)
 
-    def kernel(kw_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        a = a_ref[...]
-        rows = jax.lax.broadcasted_iota(jnp.uint32, (m_pad, block_n), 0)
-        cols = (ni * block_n).astype(jnp.uint32) + jax.lax.broadcasted_iota(
-            jnp.uint32, (m_pad, block_n), 1
+    def s_tile(refs, w, r0, rl, c0):
+        (kw_ref,) = refs
+        rows, live = fused_gram.row_mask(r0, p.gen, p.bn, m)
+        cols = jnp.asarray(c0).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+            jnp.uint32, (p.gen, p.bn), 1
         )
-        for w in range(q):  # static unroll: q accumulators, one read of A
-            s_tile = common.counter_normal(kw_ref[w, 0], kw_ref[w, 1], rows, cols) * jnp.float32(
-                inv_sqrt_m
-            )
-            s_tile = jnp.where(rows < jnp.uint32(m), s_tile, 0.0)
-            acc_ref[w] += jnp.dot(s_tile, a, preferred_element_type=jnp.float32)
+        z = common.counter_normal(kw_ref[w, 0], kw_ref[w, 1], rows, cols) * jnp.float32(inv_sqrt_m)
+        return jnp.where(live, z, 0.0)
 
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            for w in range(q):
-                acc = acc_ref[w]
-                o_ref[w] = jax.lax.dot_general(
-                    acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, 2), lambda ni: (0, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, d, d), lambda ni: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((q, m_pad, d), jnp.float32)],
+    return fused_gram.gram_multi(
+        A,
+        [(key_words, pl.BlockSpec(memory_space=pltpu.SMEM))],
+        s_tile,
+        q,
+        p,
+        name="gaussian_gram",
         interpret=interpret,
-    )(key_words, A)
+    )
 
 
 def gaussian_adjoint_tiles(

@@ -8,124 +8,56 @@ arXiv:2412.20301), but its randomness is ONE BIT per entry: one threefry call
 yields 32 packed signs (``common.packed_sign_words``), a ~64× reduction in RNG
 uint work and the complete removal of the transcendental pipeline.
 
-Kernel structure is identical to the Gaussian gram kernels (grid over row tiles,
-(m, d) VMEM accumulator, last-step Gram contraction; the multi-worker variant
-keeps q accumulators and reads A once), only the S-tile generator differs:
-words → bit-unpack → ±1, instead of threefry → Box-Muller.
+The grid, accumulators and Gram steps are :mod:`repro.kernels.fused_gram`'s, as
+for every family; only the S-tile generator differs: words → bit-unpack → ±1,
+instead of threefry → Box-Muller. VMEM at the chip smoke's shapes (d_pad=1024,
+m=10000) is the shared kernel's: 12 MiB per worker, 5.25 MiB fixed, key words in SMEM
+— q=8 runs as two launches of 4 workers, 53.25 MiB each.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-
-
-def _sign_tile(k0, k1, ni, m_pad: int, block_n: int, inv_sqrt_m: float, m: int):
-    """The (m_pad, block_n) scaled ±1/√m S tile at row-tile ni (packed contract)."""
-    col0 = (ni * block_n).astype(jnp.uint32)
-    signs = common.packed_sign_tile(k0, k1, jnp.uint32(0), col0, m_pad, block_n)
-    rows = jax.lax.broadcasted_iota(jnp.uint32, (m_pad, block_n), 0)
-    return jnp.where(rows < jnp.uint32(m), signs * jnp.float32(inv_sqrt_m), 0.0)
+from repro.kernels import common, fused_gram
 
 
 def rademacher_gram_tiles(
     A: jax.Array,
     key_words: jax.Array,
     m: int,
-    m_pad: int,
+    p: fused_gram.Plan,
     *,
-    block_n: int,
-    inv_sqrt_m: float,
     interpret: bool = True,
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) with S = ±1/√m generated in-core from packed sign words.
-    A: (n_pad, d_pad) zero-filled; ``block_n`` must be a multiple of 32 (one
-    threefry word per 32 columns). Returns (d_pad, d_pad) f32."""
-    n, d = A.shape
-    n_tiles = n // block_n
+    """All q workers' Grams with S = ±1/√m made in-core from packed sign words.
 
-    def kernel(kw_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        s_tile = _sign_tile(kw_ref[0], kw_ref[1], ni, m_pad, block_n, inv_sqrt_m, m)
-        acc_ref[...] += jnp.dot(s_tile, a_ref[...], preferred_element_type=jnp.float32)
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            acc = acc_ref[...]
-            o_ref[...] = jax.lax.dot_general(
-                acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda ni: (0,)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((d, d), lambda ni: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad, d), jnp.float32)],
-        interpret=interpret,
-    )(key_words, A)
-
-
-def rademacher_gram_tiles_multi(
-    A: jax.Array,
-    key_words: jax.Array,
-    m: int,
-    m_pad: int,
-    *,
-    block_n: int,
-    inv_sqrt_m: float,
-    interpret: bool = True,
-) -> jax.Array:
-    """All q workers' Rademacher Grams from ONE launch / ONE read of A.
-
-    ``key_words``: (q, 2). Static worker unroll over a (q, m_pad, d) scratch —
-    same shape discipline as :func:`..gaussian.gram.gaussian_gram_tiles_multi`;
-    per-worker op sequence matches :func:`rademacher_gram_tiles` (bitwise)."""
-    n, d = A.shape
+    ``A``: (n_pad, d_pad) zero-filled, ``p.bn`` a multiple of 32 (one threefry word
+    per 32 columns); ``key_words``: (q, 2) uint32. Returns (q, d_pad, d_pad) f32;
+    worker w is bitwise equal to a one-worker launch with ``key_words[w:w+1]``.
+    """
     q = key_words.shape[0]
-    n_tiles = n // block_n
+    inv_sqrt_m = 1.0 / math.sqrt(m)
 
-    def kernel(kw_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
+    def s_tile(refs, w, r0, rl, c0):
+        (kw_ref,) = refs
+        _, live = fused_gram.row_mask(r0, p.gen, p.bn, m)
+        signs = common.packed_sign_tile(
+            kw_ref[w, 0], kw_ref[w, 1], jnp.asarray(r0).astype(jnp.uint32),
+            jnp.asarray(c0).astype(jnp.uint32), p.gen, p.bn,
+        )
+        return jnp.where(live, signs * jnp.float32(inv_sqrt_m), 0.0)
 
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        a = a_ref[...]
-        for w in range(q):  # static unroll: q accumulators, one read of A
-            s_tile = _sign_tile(kw_ref[w, 0], kw_ref[w, 1], ni, m_pad, block_n, inv_sqrt_m, m)
-            acc_ref[w] += jnp.dot(s_tile, a, preferred_element_type=jnp.float32)
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            for w in range(q):
-                acc = acc_ref[w]
-                o_ref[w] = jax.lax.dot_general(
-                    acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, 2), lambda ni: (0, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, d, d), lambda ni: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((q, m_pad, d), jnp.float32)],
+    return fused_gram.gram_multi(
+        A,
+        [(key_words, pl.BlockSpec(memory_space=pltpu.SMEM))],
+        s_tile,
+        q,
+        p,
+        name="rademacher_gram",
         interpret=interpret,
-    )(key_words, A)
+    )

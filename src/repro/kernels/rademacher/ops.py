@@ -7,7 +7,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common
+from repro.kernels import common, fused_gram
 from repro.kernels.rademacher import gram as K_gram
 from repro.kernels.rademacher import kernel as K
 
@@ -59,58 +59,32 @@ def rademacher_sketch(
     return out[:, 0] if orig_ndim == 1 else out
 
 
-@functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def rademacher_gram(
     key: jax.Array, A: jax.Array, m: int, *, interpret: bool | None = None
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) ∈ R^{d×d} in one fused pass — S and SA never touch HBM."""
-    interpret = common.resolve_interpret(interpret)
-    n, d = A.shape
-    bn = _block_n(n)
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
-    k0, k1 = common.key_to_words(key)
-    key_words = jnp.stack([k0, k1])
-
-    G = K_gram.rademacher_gram_tiles(
-        Af,
-        key_words,
-        m,
-        m_pad,
-        block_n=bn,
-        inv_sqrt_m=1.0 / math.sqrt(m),
-        interpret=interpret,
-    )
-    return G[:d, :d]
+    """G = (SA)ᵀ(SA) ∈ R^{d×d} in one fused pass — S and SA never touch HBM.
+    The one-worker case of :func:`rademacher_gram_multi`."""
+    return rademacher_gram_multi(key[None], A, m, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def rademacher_gram_multi(
     keys: jax.Array, A: jax.Array, m: int, *, interpret: bool | None = None
 ) -> jax.Array:
-    """All q workers' ``G_k`` from ONE launch / ONE read of A. ``keys``: (q,)
-    PRNG keys; returns (q, d, d), slice w bitwise == ``rademacher_gram``."""
+    """All q workers' ``G_k`` from one read of A per launch. ``keys``: (q,) PRNG
+    keys; returns (q, d, d), slice w bitwise == ``rademacher_gram(keys[w], A, m)``."""
     interpret = common.resolve_interpret(interpret)
     n, d = A.shape
-    bn = _block_n(n)
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
+    q = keys.shape[0]
+    p = fused_gram.plan(q, m, n, d)
+    Af = fused_gram.pad_data(A, p)
     key_words = common.keys_to_words(keys)
-
-    G = K_gram.rademacher_gram_tiles_multi(
-        Af,
-        key_words,
-        m,
-        m_pad,
-        block_n=bn,
-        inv_sqrt_m=1.0 / math.sqrt(m),
-        interpret=interpret,
+    G = fused_gram.chunked(
+        lambda s, k: K_gram.rademacher_gram_tiles(
+            Af, key_words[s : s + k], m, p, interpret=interpret
+        ),
+        q,
+        p,
     )
     return G[:, :d, :d]
 

@@ -1,140 +1,65 @@
 """Pallas TPU kernel: fused SJLT sketch→Gram — G = (SA)ᵀ(SA) in ONE pass over A.
 
-Same single-pass structure as the Gaussian gram kernel (grid over row tiles of A, an
-(m, d) VMEM scratch accumulator that persists across the sequential grid, Gram formed
-once at the final step), but the S tile is the SJLT one-hot slice built in registers
-from the counter-derived bucket/sign parameters — the identical construction the
-apply kernel uses, so the fused Gram is the Gram of exactly that sketch.
+The grid, accumulators and Gram steps are :mod:`repro.kernels.fused_gram`'s, as for
+every family. This module supplies the S tile: S[i, j] = Σ_t sign[j, t]·[bucket[j, t]
+= i], built by comparing each global row id with the s bucket ids of the tile's
+columns (s compare-selects per entry; the counter-derived (bucket, sign) parameters
+are the ones ``SJLTOp`` uses, so the fused Gram is the Gram of exactly that sketch).
+The S tile then goes through the same MXU dot as the dense families — the old
+one-hot (n·s, m) scatter-matmul and its (nb·s, 1) reshape, which Mosaic refuses,
+are gone.
 
-Per n-tile:  acc += one_hot(bucketsᵀ) · (signs ⊙ A-replicated)   (scatter as matmul)
-Final step:  G = accᵀ · acc
-
-Padded input rows are routed to bucket −1 by the caller (no local column matches) and
-carry zero signs, so they contribute nothing; accumulator rows beyond the true m are
-never addressed because bucket ids live in [0, m).
+Parameters arrive transposed, (q, s, n_pad): a worker's bucket ids for one column
+tile are s lane-dense rows. Padded input rows carry bucket −1 (matches no row) and
+sign 0. VMEM at the chip smoke's shapes (d_pad=1024, m=10000, s=4): the shared
+kernel's 12 MiB per worker and 5.25 MiB fixed, plus 64 KiB per worker of parameter blocks
+— q=8 runs as two launches of 4 workers, 53.5 MiB each.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import common, fused_gram
+
+
+def param_bytes_per_worker(s: int) -> int:
+    """VMEM of one worker's double-buffered (s, bn) bucket and sign blocks."""
+    return 2 * 2 * 4 * common.round_up(s, 8) * fused_gram.BLOCK_N
 
 
 def sjlt_gram_tiles(
     A: jax.Array,
     buckets: jax.Array,
     signs: jax.Array,
-    m_pad: int,
+    m: int,
+    p: fused_gram.Plan,
     *,
-    block_n: int,
     interpret: bool = True,
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) for the SJLT defined by (buckets, signs). A: (n_pad, d_pad);
-    buckets/signs: (n_pad, s). Returns (d_pad, d_pad) f32."""
-    n, d = A.shape
-    s = buckets.shape[1]
-    n_tiles = n // block_n
+    """All q workers' SJLT Grams from one launch. ``A``: (n_pad, d_pad);
+    ``buckets``/``signs``: (q, s, n_pad) int32 / f32. Returns (q, d_pad, d_pad) f32;
+    worker w is bitwise equal to a one-worker launch with its parameters."""
+    q, s, _ = buckets.shape
+    spec = pl.BlockSpec((q, s, p.bn), lambda mb, ni: (0, 0, ni))
 
-    def kernel(b_ref, s_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
+    def s_tile(refs, w, r0, rl, c0):
+        b_ref, s_ref = refs
+        rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (p.gen, p.bn), 0)
+        bk = b_ref[w]
+        sg = s_ref[w]
+        tile = jnp.zeros((p.gen, p.bn), jnp.float32)
+        for t in range(s):  # s nonzeros per column
+            tile = tile + jnp.where(rows == bk[t : t + 1, :], sg[t : t + 1, :], 0.0)
+        return tile
 
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        buckets_blk = b_ref[...]
-        signs_blk = s_ref[...]
-        a = a_ref[...]
-        nb, ss = buckets_blk.shape
-        cols = jax.lax.broadcasted_iota(jnp.int32, (nb * ss, m_pad), 1)
-        flat = buckets_blk.reshape(nb * ss, 1)
-        onehot = jnp.where(cols == flat, signs_blk.reshape(nb * ss, 1), 0.0).astype(a.dtype)
-        a_rep = jnp.repeat(a, ss, axis=0)
-        acc_ref[...] += jax.lax.dot_general(
-            onehot, a_rep, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            acc = acc_ref[...]
-            o_ref[...] = jax.lax.dot_general(
-                acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((block_n, s), lambda ni: (ni, 0)),
-            pl.BlockSpec((block_n, s), lambda ni: (ni, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((d, d), lambda ni: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad, d), jnp.float32)],
+    return fused_gram.gram_multi(
+        A,
+        [(buckets, spec), (signs, spec)],
+        s_tile,
+        q,
+        p,
+        name="sjlt_gram",
         interpret=interpret,
-    )(buckets, signs, A)
-
-
-def sjlt_gram_tiles_multi(
-    A: jax.Array,
-    buckets: jax.Array,
-    signs: jax.Array,
-    m_pad: int,
-    *,
-    block_n: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """All q workers' SJLT Grams from ONE launch / ONE read of A.
-
-    ``buckets``/``signs``: (q, n_pad, s) — per-worker counter-derived parameters
-    (tiny: s ints per row vs d floats of A). The A tile *and* its s-replicated
-    copy are built once per grid step and shared across the statically-unrolled
-    worker loop; only the one-hot scatter matmul is per-worker. Per worker the op
-    sequence matches :func:`sjlt_gram_tiles`, so output slice w is bitwise equal
-    to a single launch with that worker's parameters.
-    """
-    n, d = A.shape
-    q, _, s = buckets.shape
-    n_tiles = n // block_n
-
-    def kernel(b_ref, s_ref, a_ref, o_ref, acc_ref):
-        ni = pl.program_id(0)
-
-        @pl.when(ni == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        a = a_ref[...]
-        nb = a.shape[0]
-        a_rep = jnp.repeat(a, s, axis=0)  # shared across all q workers
-        cols = jax.lax.broadcasted_iota(jnp.int32, (nb * s, m_pad), 1)
-        for w in range(q):
-            flat = b_ref[w].reshape(nb * s, 1)
-            onehot = jnp.where(cols == flat, s_ref[w].reshape(nb * s, 1), 0.0).astype(a.dtype)
-            acc_ref[w] += jax.lax.dot_general(
-                onehot, a_rep, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-        @pl.when(ni == n_tiles - 1)
-        def _finish():
-            for w in range(q):
-                acc = acc_ref[w]
-                o_ref[w] = jax.lax.dot_general(
-                    acc, acc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-                )
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, block_n, s), lambda ni: (0, ni, 0)),
-            pl.BlockSpec((q, block_n, s), lambda ni: (0, ni, 0)),
-            pl.BlockSpec((block_n, d), lambda ni: (ni, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, d, d), lambda ni: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, d, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((q, m_pad, d), jnp.float32)],
-        interpret=interpret,
-    )(buckets, signs, A)
+    )

@@ -6,7 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common
+from repro.kernels import common, fused_gram
 from repro.kernels.sjlt import gram as K_gram
 from repro.kernels.sjlt import kernel as K
 from repro.kernels.sjlt import ref as R
@@ -65,7 +65,6 @@ def sjlt_apply(
     return out[:m, :d].astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def sjlt_gram(
     A: jax.Array,
     buckets: jax.Array,
@@ -74,25 +73,9 @@ def sjlt_gram(
     *,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """G = (SA)ᵀ(SA) ∈ R^{d×d} in one fused pass over A (SA never hits HBM)."""
-    interpret = common.resolve_interpret(interpret)
-    n, d = A.shape
-    s = buckets.shape[1]
-
-    bn = min(BLOCK_N, common.round_up(n, 8))
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
-    # Padded (fictitious) rows: bucket -1 matches no accumulator column, sign 0.
-    buckets_p = common.pad_axis_to(buckets + 1, 0, n_pad) - 1
-    signs_p = common.pad_axis_to(signs.astype(jnp.float32), 0, n_pad)
-
-    G = K_gram.sjlt_gram_tiles(
-        Af, buckets_p, signs_p, m_pad, block_n=bn, interpret=interpret
-    )
-    return G[:d, :d]
+    """G = (SA)ᵀ(SA) ∈ R^{d×d} in one fused pass over A (SA never hits HBM).
+    The one-worker case of :func:`sjlt_gram_multi`."""
+    return sjlt_gram_multi(A, buckets[None], signs[None], m, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
@@ -104,26 +87,25 @@ def sjlt_gram_multi(
     *,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """All q workers' ``G_k`` for per-worker SJLT params from ONE launch.
+    """All q workers' ``G_k`` for per-worker SJLT params, one read of A per launch.
 
     ``buckets``/``signs``: (q, n, s). Returns (q, d, d) f32; worker slice w is
     bitwise-identical to ``sjlt_gram(A, buckets[w], signs[w], m)``.
     """
     interpret = common.resolve_interpret(interpret)
     n, d = A.shape
-
-    bn = min(BLOCK_N, common.round_up(n, 8))
-    n_pad = common.round_up(n, bn)
-    d_pad = common.round_up(d, 128)
-    m_pad = common.round_up(m, 8)
-
-    Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
-    # Padded (fictitious) rows: bucket -1 matches no accumulator column, sign 0.
-    buckets_p = common.pad_axis_to(buckets + 1, 1, n_pad) - 1
-    signs_p = common.pad_axis_to(signs.astype(jnp.float32), 1, n_pad)
-
-    G = K_gram.sjlt_gram_tiles_multi(
-        Af, buckets_p, signs_p, m_pad, block_n=bn, interpret=interpret
+    q, _, s = buckets.shape
+    p = fused_gram.plan(q, m, n, d, per_worker_bytes=K_gram.param_bytes_per_worker(s))
+    Af = fused_gram.pad_data(A, p)
+    # Padded (fictitious) rows: bucket -1 matches no sketch row, sign 0.
+    buckets_t = (common.pad_axis_to(buckets + 1, 1, p.n_pad) - 1).transpose(0, 2, 1)
+    signs_t = common.pad_axis_to(signs.astype(jnp.float32), 1, p.n_pad).transpose(0, 2, 1)
+    G = fused_gram.chunked(
+        lambda s0, k: K_gram.sjlt_gram_tiles(
+            Af, buckets_t[s0 : s0 + k], signs_t[s0 : s0 + k], m, p, interpret=interpret
+        ),
+        q,
+        p,
     )
     return G[:, :d, :d]
 

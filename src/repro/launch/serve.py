@@ -26,6 +26,7 @@ from repro.analysis.annotations import sanctioned_wall_timer
 from repro.configs.base import get_config
 from repro.models import lm
 from repro.serve import Engine, ServeConfig, SolveServer
+from repro.utils import env as envcfg
 
 
 def _latency_model(args):
@@ -147,6 +148,7 @@ def main():
     ap.add_argument("--target-error", type=float, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    envcfg.configure_compile_cache()
 
     if args.solve:
         return solve_main(args)

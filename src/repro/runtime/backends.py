@@ -139,7 +139,9 @@ class ProcessBackend(ExecutorBackend):
 
     ``start_method`` defaults to ``spawn``: forking after the parent initialized
     an XLA client is unsafe, and spawned children re-import JAX cleanly (the
-    dominant cost — keep ``max_workers`` small).
+    dominant cost — keep ``max_workers`` small). CPU only: on a TPU the parent
+    holds the chip, so construction raises instead of spawning children that
+    would fail or hang on it.
     """
 
     name = "process"
@@ -150,6 +152,14 @@ class ProcessBackend(ExecutorBackend):
         max_workers: int = 2,
         start_method: str = "spawn",
     ):
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "the 'process' backend starts worker processes that each initialize "
+                "JAX, but this process already holds the TPU and a chip belongs to one "
+                "process at a time; use the 'thread' or 'inline' backend on a chip"
+            )
         # Pickling up front both validates the task spec and freezes the payload
         # the initializer ships to every worker process.
         self._payload = pickle.dumps(compute_fn)

@@ -24,6 +24,7 @@ Early-stop estimators (for ``RuntimeConfig.target_error``):
 """
 from __future__ import annotations
 
+import threading
 from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
@@ -41,6 +42,11 @@ from repro.runtime.engine import (
 from repro.runtime.latency import LatencyModel
 from repro.utils import prng
 
+# Serialises the one-time upload + jit build of every payload in this process, so
+# threads racing on a new payload make one device copy of A and one program. A
+# module lock, not a field: task specs must stay picklable.
+_BUILD_LOCK = threading.Lock()
+
 
 def _key_data(key) -> np.ndarray:
     """Raw uint32 words of a jax PRNG key (legacy or typed) — picklable."""
@@ -51,7 +57,11 @@ def _key_data(key) -> np.ndarray:
 
 
 class _PicklableCompute:
-    """Base for process-shippable payloads: numpy state + a lazily built jit."""
+    """Base for process-shippable payloads: numpy state + a lazily built jit.
+
+    The jit takes (A, b) as arguments, uploaded once per process: closed over,
+    they would be embedded in the program as constants (GBs at served sizes).
+    """
 
     def __init__(self, spec: sk.SketchSpec, base_key, A, b):
         self.spec = spec
@@ -59,19 +69,36 @@ class _PicklableCompute:
         self.A = np.asarray(A)
         self.b = np.asarray(b)
         self._fn = None
+        self._data = None
 
-    def _build(self) -> Callable:
+    def _program(self) -> Callable:
+        """The jitted task program ``(wkey, A, b) -> x̂``."""
         raise NotImplementedError
 
-    def __call__(self, worker_id: int, round_id: int) -> np.ndarray:
+    def _ready(self):
         if self._fn is None:
-            self._fn = self._build()
-        wkey = prng.worker_key(jnp.asarray(self.base_key), worker_id, round_id)
-        return np.asarray(self._fn(wkey))
+            with _BUILD_LOCK:
+                if self._fn is None:  # _fn is published last: a set _fn has its _data
+                    self._data = (jnp.asarray(self.A), jnp.asarray(self.b))
+                    self._fn = self._program()
+        return self._fn, self._data
+
+    def _key(self, worker_id: int, round_id: int):
+        return prng.worker_key(jnp.asarray(self.base_key), worker_id, round_id)
+
+    def __call__(self, worker_id: int, round_id: int) -> np.ndarray:
+        fn, data = self._ready()
+        return np.asarray(fn(self._key(worker_id, round_id), *data))
+
+    def lower(self, worker_id: int = 0, round_id: int = 0):
+        """The program one task runs, lowered (``.compile().as_text()`` shows it)."""
+        fn, data = self._ready()
+        return fn.lower(self._key(worker_id, round_id), *data)
 
     def __getstate__(self):
         state = dict(self.__dict__)
-        state["_fn"] = None  # jit caches never cross process boundaries
+        state["_fn"] = None  # jit caches and device arrays never cross processes
+        state["_data"] = None
         return state
 
 
@@ -83,18 +110,19 @@ class SketchSolveCompute(_PicklableCompute):
         self.reg = float(reg)
         self.method = str(method)
 
-    def _build(self):
-        A, b = jnp.asarray(self.A), jnp.asarray(self.b)
+    def _program(self):
         spec, reg, method = self.spec, self.reg, self.method
-        return jax.jit(lambda wkey: solve.sketch_and_solve(spec, wkey, A, b, reg=reg, method=method))
+        return jax.jit(
+            lambda wkey, A, b: solve.sketch_and_solve(spec, wkey, A, b, reg=reg, method=method)
+        )
 
 
 class LeastNormCompute(_PicklableCompute):
     """§V right-sketch worker (n < d) as a task spec."""
 
-    def _build(self):
-        A, b, spec = jnp.asarray(self.A), jnp.asarray(self.b), self.spec
-        return jax.jit(lambda wkey: solve.sketch_least_norm(spec, wkey, A, b))
+    def _program(self):
+        spec = self.spec
+        return jax.jit(lambda wkey, A, b: solve.sketch_least_norm(spec, wkey, A, b))
 
 
 def make_sketch_solve_compute(

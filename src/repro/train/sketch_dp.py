@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.compat import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.core import averaging, gradcomp
@@ -88,7 +87,7 @@ def make_sketch_dp_step(
         return mean_grads, mean_loss
 
     batch_spec = {"tokens": P(axis_names), "labels": P(axis_names), "loss_mask": P(axis_names)}
-    smap = shard_map(
+    smap = jax.shard_map(
         local_grads,
         mesh=mesh,
         in_specs=(P(), batch_spec, P(), P()),

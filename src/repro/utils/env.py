@@ -20,6 +20,7 @@ This module is intentionally stdlib-only (no jax/numpy imports): it sits below
 from __future__ import annotations
 
 import os
+import pathlib
 
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
@@ -83,3 +84,30 @@ def read_int(
     if bad:
         raise ValueError(f"{name} must be {constraint}, got {value}")
     return value
+
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/utils/env.py -> the checkout root
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives: ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else the fixed ``<checkout>/.jax_cache`` (gitignored). Never a
+    temporary or per-process path: the directory is part of the cache key."""
+    return read_raw(CACHE_ENV) or str(CHECKOUT / ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Turn the persistent compilation cache on at :func:`compile_cache_dir`.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it, and nothing
+    else is set here. Call once, before the first compile, from entry points
+    (``chip_smoke.py``, ``launch/serve.py``, ``benchmarks/run.py``). Returns the path.
+    """
+    path = compile_cache_dir()
+    if not read_raw(CACHE_ENV):
+        import jax  # deferred: this module stays stdlib-only at import
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

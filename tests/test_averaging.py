@@ -63,10 +63,10 @@ def test_straggler_mask_statistics():
 
 def test_psum_average_single_device_mesh():
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.utils.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
-    f = shard_map(
+    mesh = make_mesh((1,), ("data",))
+    f = jax.shard_map(
         lambda x, m: averaging.psum_average(x, m, "data"),
         mesh=mesh,
         in_specs=(P("data"), P("data")),

@@ -7,8 +7,12 @@ with a fresh round-folded key, and innocent pool-mates (whose futures the broken
 pool also poisoned) are transparently re-run and never appear in the event log.
 """
 import pickle
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -38,6 +42,54 @@ def test_make_backend_passes_instances_through():
     inline = rt.InlineBackend(lambda w, r: np.zeros(2))
     assert rt.make_backend(inline, lambda w, r: np.ones(2)) is inline
     assert set(rt.BACKENDS) == {"inline", "thread", "process"}
+
+
+def test_make_backend_process_refuses_a_tpu_parent(monkeypatch):
+    """A chip belongs to one process: on a TPU the process backend must refuse
+    instead of spawning children that would fail or hang on the held chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        rt.make_backend("process", lambda w, r: np.zeros(2))
+    assert isinstance(rt.make_backend("thread", lambda w, r: np.zeros(2)), rt.ThreadBackend)
+
+
+def test_task_program_takes_data_as_arguments():
+    """The task jit lowers with (key, A, b) as parameters — A is not a constant."""
+    key, A, b = _toy_problem()
+    compute = rt.make_sketch_solve_compute(sk.SketchSpec("gaussian", 64), key, A, b)
+    lowered = compute.lower(1, 0)
+    assert [x.shape for x in jax.tree_util.tree_leaves(lowered.in_avals)][1:] == [A.shape, b.shape]
+    np.testing.assert_allclose(
+        np.asarray(lowered.compile()(compute._key(1, 0), jnp.asarray(A), jnp.asarray(b))),
+        compute(1, 0),
+        rtol=1e-6,
+    )
+
+
+def test_task_program_is_built_once_under_racing_threads():
+    """Eight threads hitting a fresh payload at once make one device copy and one jit."""
+    key, A, b = _toy_problem()
+    builds = []
+
+    class Counting(rt.SketchSolveCompute):
+        def _program(self):
+            builds.append(threading.get_ident())
+            time.sleep(0.05)  # hold the build open so unguarded threads would pile in
+            return super()._program()
+
+    compute = Counting(sk.SketchSpec("gaussian", 64), key, A, b)
+    start = threading.Barrier(8)
+
+    def task(w):
+        start.wait()
+        return compute(w, 0)
+
+    with ThreadPoolExecutor(8) as pool:
+        xs = list(pool.map(task, range(8)))
+    assert len(builds) == 1
+    for w, x in enumerate(xs):
+        np.testing.assert_array_equal(x, compute(w, 0))
+    assert len(builds) == 1
 
 
 def test_sketch_solve_compute_pickle_roundtrip():

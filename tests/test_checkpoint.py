@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint, save_checkpoint
+from repro.launch.mesh import make_mesh
 
 
 def _tree(key):
@@ -83,7 +84,7 @@ def test_elastic_restore_onto_mesh(tmp_path):
 
     t = {"w": jnp.arange(16.0).reshape(4, 4)}
     save_checkpoint(str(tmp_path), 2, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     r = restore_checkpoint(str(tmp_path), 2, jax.eval_shape(lambda: t), shardings=sh)
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(t["w"]))

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.data import regression
 from repro.data import (
     airline_like,
     emnist_like,
@@ -58,3 +59,16 @@ def test_regression_generators():
     A, B, meta = emnist_like(jax.random.PRNGKey(0), 64, classes=5, img_dim=16)
     assert B.shape == (64, 5)
     np.testing.assert_allclose(np.asarray(B.sum(axis=1)), 1.0)
+
+
+def test_student_t_row_blocks_match_shape_and_tail(monkeypatch):
+    """Row-blocked draws (how large n fits a device) keep shape, clip and planting."""
+    monkeypatch.setattr(regression, "T_BLOCK_ROWS", 256)  # 4 blocks, the last one cut
+    A, b, meta = student_t_regression(jax.random.PRNGKey(0), 1000, 8, df=1.5)
+    assert A.shape == (1000, 8) and b.shape == (1000,)
+    assert float(jnp.max(jnp.abs(A))) <= 1e3
+    assert not np.array_equal(np.asarray(A[:256]), np.asarray(A[256:512]))  # fresh key per block
+    resid = b - A @ meta["x_truth"]
+    assert float(jnp.std(resid)) < 0.2
+    A2, _, _ = student_t_regression(jax.random.PRNGKey(0), 1000, 8, df=1.5)
+    np.testing.assert_array_equal(np.asarray(A), np.asarray(A2))

@@ -75,3 +75,25 @@ def test_kernel_knobs_route_through_env_surface(monkeypatch):
     assert common.default_interpret() is True
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     assert common.default_interpret() is False
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    updates = []
+    import jax
+
+    monkeypatch.setattr(jax.config, "update", lambda name, value: updates.append((name, value)))
+    path = env.configure_compile_cache()
+    assert path == str(env.CHECKOUT / ".jax_cache")
+    assert (env.CHECKOUT / "chip_smoke.py").exists()  # CHECKOUT is the repo root
+    assert updates == [("jax_compilation_cache_dir", path)]
+
+
+def test_compile_cache_env_dir_is_used_and_nothing_else_set(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    updates = []
+    import jax
+
+    monkeypatch.setattr(jax.config, "update", lambda name, value: updates.append((name, value)))
+    assert env.configure_compile_cache() == str(tmp_path)
+    assert updates == []

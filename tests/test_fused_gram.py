@@ -185,7 +185,8 @@ def test_apply_batched_mesh_matches_loop_bitwise():
         n, d, m, q = 512, 8, 64, 8
         A = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         keys = prng.worker_keys(jax.random.PRNGKey(1), q)
-        mesh = jax.make_mesh((8,), ("workers",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("workers",))
         for spec in (sk.SketchSpec("srht", m), sk.SketchSpec("gaussian", m)):
             meshed = ops.apply_batched(spec, keys, A, mesh=mesh, axis_names=("workers",))
             looped_ref = jax.lax.map(lambda k: ops.apply(spec, k, A), keys)
@@ -213,7 +214,8 @@ def test_gram_batched_mesh_matches_loop():
         A = jax.random.normal(jax.random.PRNGKey(0), (n, d))
         b = jax.random.normal(jax.random.PRNGKey(2), (n,))
         keys = prng.worker_keys(jax.random.PRNGKey(1), q)
-        mesh = jax.make_mesh((8,), ("workers",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("workers",))
         spec = sk.SketchSpec("gaussian", m)
         Gs_m, cs_m = ops.gram_batched(spec, keys, A, b, mesh=mesh, axis_names=("workers",))
         Gs_l, cs_l = ops.gram_batched(spec, keys, A, b)

@@ -37,7 +37,8 @@ def test_distributed_sketch_solve_matches_local_average():
         n, d, m = 2048, 16, 128
         A = jax.random.normal(key, (n, d))
         b = jax.random.normal(jax.random.PRNGKey(1), (n,))
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         spec = sk.SketchSpec("gaussian", m)
         xbar = distributed.distributed_sketch_solve(mesh, spec, key, A, b)
         # reference: same worker keys, computed locally
@@ -68,7 +69,8 @@ def test_distributed_least_norm_and_multiround():
         n, d = 16, 256
         A = jax.random.normal(key, (n, d))
         b = jax.random.normal(jax.random.PRNGKey(1), (n,))
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         spec = sk.SketchSpec("gaussian", 4 * n)
         xbar = distributed.distributed_sketch_least_norm(mesh, spec, key, A, b)
         x_star = solve.least_norm(A, b)
@@ -98,7 +100,8 @@ def test_sketch_dp_training_step_runs():
         cfg = dataclasses.replace(get_config('granite-3-8b').reduced(),
                                   num_layers=2, d_model=32, d_ff=64, num_heads=2,
                                   num_kv_heads=1, head_dim=16, vocab_size=97)
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         comp = gradcomp.GradCompressionConfig(enabled=True, ratio=0.1, kind='countsketch')
         step = make_sketch_dp_step(cfg, AdamWConfig(lr=1e-3), mesh, comp=comp)
         state = init_train_state(cfg, AdamWConfig(lr=1e-3), jax.random.PRNGKey(0))
@@ -136,7 +139,8 @@ def test_sharded_train_step_compiles_on_mini_mesh():
         cfg = dataclasses.replace(get_config('granite-3-8b').reduced(),
                                   num_layers=2, d_model=32, d_ff=64, num_heads=4,
                                   num_kv_heads=2, head_dim=16, vocab_size=256)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = ShardingRules(dp=("pod", "data"), fsdp="data", tensor="model")
         opt = AdamWConfig(lr=1e-3)
         named = lambda tree: jax.tree_util.tree_map(
@@ -168,13 +172,15 @@ def test_elastic_checkpoint_rescale():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_checkpoint
 
-        mesh8 = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+
+        mesh8 = make_mesh((8,), ("data",))
         x = jnp.arange(64.0).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh8, P("data", None)))
         d = tempfile.mkdtemp()
         save_checkpoint(d, 1, {"w": xs})
 
-        mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh4 = make_mesh((4, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh4, P("data", "model"))}
         r = restore_checkpoint(d, 1, jax.eval_shape(lambda: {"w": x}), shardings=sh)
         np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(x))

@@ -68,16 +68,36 @@ def test_fused_multi_matrix_b():
 
 
 def test_gram_batched_kernel_base_returns_notimplemented():
-    """Kinds without a multi-worker kernel fall back to per-key dispatch."""
-    assert (
-        ops.SketchOp.gram_batched_kernel(sk.SketchSpec("uniform", M), None, None, None)
-        is NotImplemented
-    )
+    """Kinds without a multi-worker kernel have none by class (no run-time
+    fallback): the base and the sampling kinds carry ``None`` and take per-key
+    dispatch; the four kernel families carry the kernel."""
+    assert ops.SketchOp.gram_batched_kernel is None
+    assert ops.make_operator(sk.SketchSpec("uniform", M), jax.random.PRNGKey(0), N).gram_batched_kernel is None
+    for kind in KERNEL_KINDS:
+        assert ops._REGISTRY[kind].gram_batched_kernel is not None, kind
     # ... and gram_batched still works for them with use_kernel-less specs.
     A = jax.random.normal(jax.random.PRNGKey(0), (N, D))
     keys = prng.worker_keys(jax.random.PRNGKey(2), Q)
     Gs, cs = ops.gram_batched(sk.SketchSpec("uniform", M), keys, A)
     assert Gs.shape == (Q, D, D) and cs is None
+
+
+def test_one_device_mesh_takes_the_multi_worker_kernel(monkeypatch):
+    """A mesh of one worker shard (one chip) shards nothing, so gram_batched keeps
+    the one-launch kernel even where mesh batching is on."""
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setenv("REPRO_MESH_BATCH", "1")
+    calls = []
+    cls = ops._REGISTRY["gaussian"]
+    fused = cls.gram_batched_kernel
+    monkeypatch.setattr(cls, "gram_batched_kernel", lambda *a: calls.append(1) or fused(*a))
+    A = jax.random.normal(jax.random.PRNGKey(0), (N, D))
+    keys = prng.worker_keys(jax.random.PRNGKey(2), Q)
+    mesh = make_mesh((1,), ("workers",))
+    Gs, _ = ops.gram_batched(_spec("gaussian"), keys, A, mesh=mesh, axis_names=("workers",))
+    assert calls == [1] and Gs.shape == (Q, D, D)
+    assert not ops._mesh_shards_keys(mesh, ("workers",), Q)
 
 
 # ------------------------------------------------------------- rademacher family

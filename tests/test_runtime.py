@@ -13,6 +13,7 @@ import pytest
 
 from repro import runtime as rt
 from repro.core import distributed, sketches as sk, solve
+from repro.launch.mesh import make_mesh
 from repro.utils import prng
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -346,7 +347,8 @@ def test_runtime_matches_masked_distributed_solve():
         n, d = 2048, 16
         A = jax.random.normal(key, (n, d))
         b = jax.random.normal(jax.random.PRNGKey(1), (n,))
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
 
         for spec in [
             sk.SketchSpec("gaussian", 128),
@@ -389,7 +391,7 @@ def _small_lsq():
 
 def test_all_straggler_eager_mask_raises():
     key, A, b = _small_lsq()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = sk.SketchSpec("gaussian", 64)
     zero = jnp.zeros((1,), jnp.float32)
     for call in (
@@ -408,7 +410,7 @@ def test_all_straggler_eager_mask_raises():
 
 def test_all_straggler_traced_mask_nan_poisons():
     key, A, b = _small_lsq()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = sk.SketchSpec("gaussian", 64)
     zero = jnp.zeros((1,), jnp.float32)
     ones = jnp.ones((1,), jnp.float32)
@@ -447,7 +449,7 @@ def test_all_straggler_traced_mask_nan_poisons():
 
 def test_multiround_traces_once_and_matches_reference():
     key, A, b = _small_lsq()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = sk.SketchSpec("gaussian", 64)
     rounds = 4
 
@@ -470,7 +472,7 @@ def test_multiround_latency_delegates_to_engine():
     """latency= makes multiround a thin wrapper over the async engine; with a
     no-straggler model it reproduces the synchronous result."""
     key, A, b = _small_lsq()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = sk.SketchSpec("gaussian", 64)
     sync = distributed.distributed_sketch_solve_multiround(mesh, spec, key, A, b, rounds=3)
     asyn = distributed.distributed_sketch_solve_multiround(

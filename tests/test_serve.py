@@ -149,7 +149,8 @@ def test_submit_solve_matches_masked_distributed_solve():
         n, d = 2048, 16
         A = jax.random.normal(key, (n, d))
         b = jax.random.normal(jax.random.PRNGKey(1), (n,))
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
 
         for spec in [sk.SketchSpec("gaussian", 128), sk.SketchSpec("sjlt", 128, s=4)]:
             lat = rt.DropLatency(
