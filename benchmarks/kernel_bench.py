@@ -1,9 +1,9 @@
-"""Pallas kernel micro-bench: interpret-mode wall time (CPU) + structural roofline.
+"""Pallas kernel micro-bench: interpret-mode wall time (CPU).
 
 Wall times here are *interpret-mode* (Python-executed kernel bodies) — they validate
-plumbing, not TPU speed. The meaningful numbers are the structural FLOP/byte terms
-from each kernel's ``flops_and_bytes`` (the quantities the TPU roofline uses), and
-the HBM-bytes saving of the RNG-fused Gaussian sketch vs a materialized S.
+plumbing, not TPU speed. Kernel time and roofline share on the chip come from the
+benchmark's profiler trace (``bench/run.py --trace 1``, work counted by
+``bench/work.py``).
 """
 from __future__ import annotations
 
@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from repro.kernels.fwht import ops as fwht_ops
 from repro.kernels.gaussian import ops as g_ops
 from repro.kernels.sjlt import ops as sjlt_ops
-from repro.roofline.hw import V5E
 from benchmarks.common import print_table, smoke, timeit, write_csv
 
 
@@ -23,41 +22,15 @@ def run(quick: bool = True):
         n, d, m = 512, 128, 128
     key = jax.random.PRNGKey(0)
     A = jax.random.normal(key, (n, d), jnp.float32)
-    rows = []
-
-    t = timeit(lambda: fwht_ops.fwht(A), repeat=2)
-    fb = fwht_ops.flops_and_bytes(n, d)
-    rows.append({
-        "kernel": "fwht", "interp_ms": t * 1e3, "flops": fb["flops"], "bytes": fb["bytes"],
-        "tpu_compute_us": fb["flops"] / V5E.peak_flops_bf16 * 1e6,
-        "tpu_memory_us": fb["bytes"] / V5E.hbm_bw * 1e6,
-    })
-
     buckets, signs = sjlt_ops.sjlt_params(key, n, s, m)
-    t = timeit(lambda: sjlt_ops.sjlt_apply(A, buckets, signs, m), repeat=2)
-    fb = sjlt_ops.flops_and_bytes(n, d, m, s)
-    rows.append({
-        "kernel": "sjlt", "interp_ms": t * 1e3, "flops": fb["flops"], "bytes": fb["bytes"],
-        "tpu_compute_us": fb["flops"] / V5E.peak_flops_bf16 * 1e6,
-        "tpu_memory_us": fb["bytes"] / V5E.hbm_bw * 1e6,
-    })
-
-    t = timeit(lambda: g_ops.gaussian_sketch(key, A, m), repeat=2)
-    fb = g_ops.flops_and_bytes(n, d, m)
-    rows.append({
-        "kernel": "gaussian_rng_fused", "interp_ms": t * 1e3, "flops": fb["flops"], "bytes": fb["bytes"],
-        "tpu_compute_us": fb["flops"] / V5E.peak_flops_bf16 * 1e6,
-        "tpu_memory_us": fb["bytes"] / V5E.hbm_bw * 1e6,
-    })
-    rows.append({
-        "kernel": "gaussian_materialized(ref)", "interp_ms": float("nan"),
-        "flops": fb["flops"], "bytes": fb["bytes_materialized"],
-        "tpu_compute_us": fb["flops"] / V5E.peak_flops_bf16 * 1e6,
-        "tpu_memory_us": fb["bytes_materialized"] / V5E.hbm_bw * 1e6,
-    })
-
+    kernels = {
+        "fwht": lambda: fwht_ops.fwht(A),
+        "sjlt": lambda: sjlt_ops.sjlt_apply(A, buckets, signs, m),
+        "gaussian_rng_fused": lambda: g_ops.gaussian_sketch(key, A, m),
+    }
+    rows = [{"kernel": name, "interp_ms": timeit(fn, repeat=2) * 1e3} for name, fn in kernels.items()]
     write_csv("kernel_bench", rows)
-    print_table("Pallas kernels (interpret wall + structural roofline)", rows)
+    print_table("Pallas kernels (interpret wall)", rows)
     return rows
 
 

@@ -201,7 +201,8 @@ def _join_b(A: jax.Array, b: Optional[jax.Array]):
     if b is None:
         return A
     bm = b if b.ndim == 2 else b[:, None]
-    return jnp.concatenate([A, bm.astype(A.dtype)], axis=1)
+    with jax.named_scope(kcommon.GRAM_INPUT_SCOPE):
+        return jnp.concatenate([A, bm.astype(A.dtype)], axis=1)
 
 
 def _split_gram(Gf: jax.Array, d: int, b: Optional[jax.Array]):
@@ -512,14 +513,18 @@ class SRHTOp(SketchOp):
     @classmethod
     def build(cls, spec, key, n, *, scores=None):
         n_pad = sk.next_pow2(n)
-        kd, kp = jax.random.split(key)
-        kd0, kd1 = kcommon.key_to_words(kd)
-        rows = jax.random.randint(kp, (spec.m,), 0, n_pad)
+        with jax.named_scope(kcommon.SKETCH_PARAMS_SCOPE):
+            kd, kp = jax.random.split(key)
+            kd0, kd1 = kcommon.key_to_words(kd)
+            rows = jax.random.randint(kp, (spec.m,), 0, n_pad)
         return cls(spec=spec, key=key, n=n, kd0=kd0, kd1=kd1, rows=rows, n_pad=n_pad)
 
     def _signs(self, j: jax.Array) -> jax.Array:
         """Rademacher diagonal D at (possibly traced) coordinate(s) j."""
-        return kcommon.counter_rademacher(self.kd0, self.kd1, j.astype(jnp.uint32), jnp.uint32(0))
+        with jax.named_scope(kcommon.SKETCH_PARAMS_SCOPE):
+            return kcommon.counter_rademacher(
+                self.kd0, self.kd1, j.astype(jnp.uint32), jnp.uint32(0)
+            )
 
     def apply(self, A: jax.Array) -> jax.Array:
         A2, batch = _to_2d(A, self.n)
@@ -586,7 +591,8 @@ class SRHTOp(SketchOp):
             rows = jax.random.randint(kp, (spec.m,), 0, n_pad)
             return rows, jnp.stack([kd0, kd1])
 
-        rows, key_words = jax.vmap(params)(keys)
+        with jax.named_scope(kcommon.SKETCH_PARAMS_SCOPE):
+            rows, key_words = jax.vmap(params)(keys)
         Gf = fops.srht_gram_multi(_join_b(A, b), rows, key_words)
         return _split_gram_batched(Gf, A.shape[1], b)
 
@@ -695,7 +701,8 @@ class SJLTOp(SketchOp):
         return cls(spec=spec, key=key, n=n, k0=k0, k1=k1)
 
     def _params(self, row_idx: jax.Array):
-        return kcommon.sjlt_counter_params(self.k0, self.k1, row_idx, self.spec.s, self.m)
+        with jax.named_scope(kcommon.SKETCH_PARAMS_SCOPE):
+            return kcommon.sjlt_counter_params(self.k0, self.k1, row_idx, self.spec.s, self.m)
 
     def _segment_apply(self, A2: jax.Array, row_idx: jax.Array) -> jax.Array:
         buckets, signs = self._params(row_idx)
@@ -747,11 +754,12 @@ class SJLTOp(SketchOp):
     def gram_batched_kernel(cls, spec, keys, A, b):
         from repro.kernels.sjlt import ops as sops
 
-        row_idx = jnp.arange(A.shape[0])
-        words = kcommon.keys_to_words(keys)  # (q, 2) — same words build() derives
-        buckets, signs = jax.vmap(
-            lambda w: kcommon.sjlt_counter_params(w[0], w[1], row_idx, spec.s, spec.m)
-        )(words)
+        with jax.named_scope(kcommon.SKETCH_PARAMS_SCOPE):
+            row_idx = jnp.arange(A.shape[0])
+            words = kcommon.keys_to_words(keys)  # (q, 2) — same words build() derives
+            buckets, signs = jax.vmap(
+                lambda w: kcommon.sjlt_counter_params(w[0], w[1], row_idx, spec.s, spec.m)
+            )(words)
         Gf = sops.sjlt_gram_multi(_join_b(A, b), buckets, signs, spec.m)
         return _split_gram_batched(Gf, A.shape[1], b)
 

@@ -14,6 +14,9 @@ import jax.numpy as jnp
 
 from repro.core import operators, sketches as sk
 
+# ``jax.named_scope`` of the d×d Cholesky tail's ops, in every path that solves a Gram.
+SOLVE_TAIL_SCOPE = "repro.solve_tail"
+
 
 # --------------------------------------------------------------------------- direct
 
@@ -74,9 +77,10 @@ def lstsq_gram(G: jax.Array, c: jax.Array, *, reg: float = 0.0) -> jax.Array:
     (:meth:`repro.core.operators.SketchOp.gram_blocked`); nothing here ever sees SA.
     """
     d = G.shape[0]
-    L = jnp.linalg.cholesky(G + reg * jnp.eye(d, dtype=G.dtype))
-    y = jax.scipy.linalg.solve_triangular(L, c, lower=True)
-    return jax.scipy.linalg.solve_triangular(L.T, y, lower=False)
+    with jax.named_scope(SOLVE_TAIL_SCOPE):
+        L = jnp.linalg.cholesky(G + reg * jnp.eye(d, dtype=G.dtype))
+        y = jax.scipy.linalg.solve_triangular(L, c, lower=True)
+        return jax.scipy.linalg.solve_triangular(L.T, y, lower=False)
 
 
 def least_norm(A: jax.Array, b: jax.Array) -> jax.Array:

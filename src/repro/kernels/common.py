@@ -16,6 +16,12 @@ import jax.numpy as jnp
 
 from repro.utils import env as envcfg
 
+# ``jax.named_scope`` names of the ops made before a fused-Gram kernel, as a device
+# trace shows them: the join and zero-pad of [A | b] to the kernel's layout, and the
+# sketch's own per-row parameters (SJLT buckets and signs, SRHT rows and signs).
+GRAM_INPUT_SCOPE = "repro.gram.input"
+SKETCH_PARAMS_SCOPE = "repro.gram.sketch_params"
+
 _ROT = (13, 15, 26, 6, 17, 29, 16, 24)
 _PARITY = np.uint32(0x1BD11BDA)
 DEFAULT_ROUNDS = 20

@@ -124,7 +124,8 @@ def plan(
 
 def pad_data(A: jax.Array, p: Plan) -> jax.Array:
     """f32 A zero-padded to (n_pad, d_pad); zero rows/columns add nothing to G."""
-    return common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, p.n_pad), 1, p.d_pad)
+    with jax.named_scope(common.GRAM_INPUT_SCOPE):
+        return common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, p.n_pad), 1, p.d_pad)
 
 
 def gram_multi(

@@ -91,7 +91,8 @@ def srht_gram_multi(
     q, m = rows.shape
     p = fused_gram.plan(q, m, n, d, per_row_bytes=K_gram.ROW_ID_BYTES)
     Af = fused_gram.pad_data(A, p)
-    rows_p = (common.pad_axis_to(rows.astype(jnp.int32) + 1, 1, p.m_pad) - 1)[..., None]
+    with jax.named_scope(common.SKETCH_PARAMS_SCOPE):
+        rows_p = (common.pad_axis_to(rows.astype(jnp.int32) + 1, 1, p.m_pad) - 1)[..., None]
     G = fused_gram.chunked(
         lambda s, k: K_gram.srht_gram_tiles(
             Af, rows_p[s : s + k], key_words[s : s + k], m, p, interpret=interpret
@@ -101,14 +102,3 @@ def srht_gram_multi(
     )
     return G[:, :d, :d]
 
-
-def flops_and_bytes(n: int, d: int) -> dict:
-    """Structural roofline terms for one FWHT (matmul formulation)."""
-    tile = min(n, MAX_TILE_ROWS)
-    n1 = n // tile
-    k = min(128, tile)
-    b = tile // k
-    f = 2 * n * d * (k + b)  # pass 1
-    if n1 > 1:
-        f += 2 * n * d * n1  # pass 2
-    return {"flops": f, "bytes": 4 * n * d * (2 if n1 == 1 else 4)}

@@ -123,13 +123,3 @@ def gaussian_adjoint(key: jax.Array, Y: jax.Array, n: int, *, interpret: bool | 
     out = out[:n, :k].astype(dtype)
     return out[:, 0] if orig_ndim == 1 else out
 
-
-def flops_and_bytes(n: int, d: int, m: int) -> dict:
-    """Structural roofline terms: matmul FLOPs + fused-RNG generation, but only
-    O((n+m)·d) HBM bytes — S never exists in memory."""
-    rng_flops_per_elem = 60  # ~20 rounds × 3 uint ops (adds/xors/rots counted as 1)
-    return {
-        "flops": 2 * m * n * d + rng_flops_per_elem * m * n,
-        "bytes": 4 * (n * d + m * d),
-        "bytes_materialized": 4 * (m * n + n * d + m * d),
-    }

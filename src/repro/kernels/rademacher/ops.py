@@ -88,13 +88,3 @@ def rademacher_gram_multi(
     )
     return G[:, :d, :d]
 
-
-def flops_and_bytes(n: int, d: int, m: int) -> dict:
-    """Structural roofline: same matmul as the Gaussian sketch but ~2 uint ops of
-    RNG per element (120/32 threefry amortized + unpack) instead of ~60+."""
-    rng_flops_per_elem = 4  # 120-op threefry per 32 entries + shift/mask/ select
-    return {
-        "flops": 2 * m * n * d + rng_flops_per_elem * m * n,
-        "bytes": 4 * (n * d + m * d),
-        "bytes_materialized": 4 * (m * n + n * d + m * d),
-    }

@@ -56,8 +56,9 @@ def sjlt_apply(
     Af = common.pad_axis_to(common.pad_axis_to(A.astype(jnp.float32), 0, n_pad), 1, d_pad)
     # Padded (fictitious) input rows must not contribute: route them to bucket -1,
     # which no m-tile's local iota can match.
-    buckets_p = common.pad_axis_to(buckets + 1, 0, n_pad) - 1
-    signs_p = common.pad_axis_to(signs.astype(jnp.float32), 0, n_pad)
+    with jax.named_scope(common.SKETCH_PARAMS_SCOPE):
+        buckets_p = common.pad_axis_to(buckets + 1, 0, n_pad) - 1
+        signs_p = common.pad_axis_to(signs.astype(jnp.float32), 0, n_pad)
 
     out = K.sjlt_tiles(
         Af, buckets_p, signs_p, m_pad, block_m=bm, block_n=bn, block_d=bd, interpret=interpret
@@ -98,8 +99,9 @@ def sjlt_gram_multi(
     p = fused_gram.plan(q, m, n, d, per_worker_bytes=K_gram.param_bytes_per_worker(s))
     Af = fused_gram.pad_data(A, p)
     # Padded (fictitious) rows: bucket -1 matches no sketch row, sign 0.
-    buckets_t = (common.pad_axis_to(buckets + 1, 1, p.n_pad) - 1).transpose(0, 2, 1)
-    signs_t = common.pad_axis_to(signs.astype(jnp.float32), 1, p.n_pad).transpose(0, 2, 1)
+    with jax.named_scope(common.SKETCH_PARAMS_SCOPE):
+        buckets_t = (common.pad_axis_to(buckets + 1, 1, p.n_pad) - 1).transpose(0, 2, 1)
+        signs_t = common.pad_axis_to(signs.astype(jnp.float32), 1, p.n_pad).transpose(0, 2, 1)
     G = fused_gram.chunked(
         lambda s0, k: K_gram.sjlt_gram_tiles(
             Af, buckets_t[s0 : s0 + k], signs_t[s0 : s0 + k], m, p, interpret=interpret
@@ -117,8 +119,3 @@ def sjlt_sketch(
     buckets, signs = sjlt_params(key, A.shape[0], s, m, dtype=jnp.float32)
     return sjlt_apply(A, buckets, signs, m, interpret=interpret)
 
-
-def flops_and_bytes(n: int, d: int, m: int, s: int) -> dict:
-    """Structural cost: the kernel is a (n·s, m)×(n·s, d) accumulation walked in
-    m-tiles; useful-work view is 2·n·s·d MACs (each nonzero touches d values)."""
-    return {"flops": 2 * n * s * d, "bytes": 4 * (n * d + m * d + n * s * 2)}

@@ -42,6 +42,13 @@ from repro.runtime.engine import (
 from repro.runtime.latency import LatencyModel
 from repro.utils import prng
 
+# Host spans (``jax.profiler.TraceAnnotation``) each task writes into a profiler
+# trace when one is active: the task on its worker thread, and inside it the device
+# copy of A and b its payload makes once (enqueued, so the span counts copies; its
+# length is not the copy's).
+TASK_SPAN = "repro.task"
+UPLOAD_SPAN = "repro.task.upload"
+
 # Serialises the one-time upload + jit build of every payload in this process, so
 # threads racing on a new payload make one device copy of A and one program. A
 # module lock, not a field: task specs must stay picklable.
@@ -61,6 +68,8 @@ class _PicklableCompute:
 
     The jit takes (A, b) as arguments, uploaded once per process: closed over,
     they would be embedded in the program as constants (GBs at served sizes).
+    ``job`` is the id of the served job the payload belongs to (set by
+    ``SolveServer``; ``None`` otherwise), written on its host spans.
     """
 
     def __init__(self, spec: sk.SketchSpec, base_key, A, b):
@@ -68,6 +77,7 @@ class _PicklableCompute:
         self.base_key = _key_data(base_key)
         self.A = np.asarray(A)
         self.b = np.asarray(b)
+        self.job: Optional[int] = None
         self._fn = None
         self._data = None
 
@@ -79,7 +89,10 @@ class _PicklableCompute:
         if self._fn is None:
             with _BUILD_LOCK:
                 if self._fn is None:  # _fn is published last: a set _fn has its _data
-                    self._data = (jnp.asarray(self.A), jnp.asarray(self.b))
+                    with jax.profiler.TraceAnnotation(
+                        UPLOAD_SPAN, job=self.job, bytes=self.A.nbytes + self.b.nbytes
+                    ):
+                        self._data = (jnp.asarray(self.A), jnp.asarray(self.b))
                     self._fn = self._program()
         return self._fn, self._data
 
@@ -87,8 +100,9 @@ class _PicklableCompute:
         return prng.worker_key(jnp.asarray(self.base_key), worker_id, round_id)
 
     def __call__(self, worker_id: int, round_id: int) -> np.ndarray:
-        fn, data = self._ready()
-        return np.asarray(fn(self._key(worker_id, round_id), *data))
+        with jax.profiler.TraceAnnotation(TASK_SPAN, job=self.job, worker=worker_id, round=round_id):
+            fn, data = self._ready()
+            return np.asarray(fn(self._key(worker_id, round_id), *data))
 
     def lower(self, worker_id: int = 0, round_id: int = 0):
         """The program one task runs, lowered (``.compile().as_text()`` shows it)."""

@@ -32,6 +32,10 @@ from repro.models import lm
 
 PyTree = object
 
+# Host span around each admitted sketch-solve job (``jax.profiler.TraceAnnotation``);
+# the job's task spans (``repro.runtime.tasks``) carry the same ``job`` id.
+JOB_SPAN = "repro.serve.job"
+
 
 def sample_token(key: jax.Array, logits: jax.Array, temperature: float = 0.0) -> jax.Array:
     """(B, V) logits -> (B,) token ids. temperature<=0 is greedy."""
@@ -222,36 +226,39 @@ class SolveServer:
         from repro.runtime import tasks as rt_tasks
         from repro.runtime.engine import ServerlessEngine
 
-        if key is None:
-            key = jax.random.PRNGKey(seed)
-        if least_norm:
-            compute = rt_tasks.make_least_norm_compute(spec, key, A, b)
-        else:
-            compute = rt_tasks.make_sketch_solve_compute(
-                spec, key, A, b, reg=reg, method=method
+        job_id = len(self.jobs)
+        with jax.profiler.TraceAnnotation(JOB_SPAN, job=job_id):
+            if key is None:
+                key = jax.random.PRNGKey(seed)
+            if least_norm:
+                compute = rt_tasks.make_least_norm_compute(spec, key, A, b)
+            else:
+                compute = rt_tasks.make_sketch_solve_compute(
+                    spec, key, A, b, reg=reg, method=method
+                )
+            compute.job = job_id
+            err = rt_tasks.resolve_error_fn(error_fn, spec, key, A, b, probe_rows=probe_rows)
+
+            engine = ServerlessEngine(
+                compute, self.latency, self.config,
+                backend=self.backend, deadline=self.deadline,
             )
-        err = rt_tasks.resolve_error_fn(error_fn, spec, key, A, b, probe_rows=probe_rows)
+            task_list = [(w, r) for r in range(rounds) for w in range(q)]
+            result = engine.run(tasks=task_list, error_fn=err)
+            if save_events is not None:
+                result.events.to_jsonl(save_events)
 
-        engine = ServerlessEngine(
-            compute, self.latency, self.config,
-            backend=self.backend, deadline=self.deadline,
-        )
-        task_list = [(w, r) for r in range(rounds) for w in range(q)]
-        result = engine.run(tasks=task_list, error_fn=err)
-        if save_events is not None:
-            result.events.to_jsonl(save_events)
-
-        backend_name = self.backend if isinstance(self.backend, str) else self.backend.name
-        job = SolveJob(
-            job_id=len(self.jobs),
-            spec=spec,
-            q=int(q),
-            backend=backend_name,
-            result=result,
-            summary=result.summary(deadline=self.config.deadline_s),
-        )
-        self.jobs.append(job)
-        return job
+            backend_name = self.backend if isinstance(self.backend, str) else self.backend.name
+            job = SolveJob(
+                job_id=job_id,
+                spec=spec,
+                q=int(q),
+                backend=backend_name,
+                result=result,
+                summary=result.summary(deadline=self.config.deadline_s),
+            )
+            self.jobs.append(job)
+            return job
 
     # ------------------------------------------------------------------ telemetry
 
