@@ -8,12 +8,15 @@ sketch→Gram pipeline by default — and the exact key schedule
 ``prng.worker_key(base_key, w, round)`` of the ``shard_map`` workers, so an async
 run and a mesh run with the same realized worker set agree to float tolerance.
 
-The payloads are *picklable task specs* (plain classes over numpy state, the jit
-cache rebuilt lazily per process), which is what lets the ``process`` executor
-backend ship one payload to each worker process and submit bare
-``(worker_id, round_id)`` coordinates afterwards. On the thread/inline backends
-they behave exactly like the closures they replaced — the jitted solve is
-compiled once per payload and shared by every thread.
+The payloads are *picklable task specs* (plain classes that pickle as numpy state,
+the jitted program found again lazily in each process), which is what lets the
+``process`` executor backend ship one payload to each worker process and submit
+bare ``(worker_id, round_id)`` coordinates afterwards. On the thread/inline
+backends they behave exactly like the closures they replaced. The jitted solve
+is built once per process per static configuration (the payload's type, sketch
+spec and, for sketch-solve, ``reg`` and ``method``) and shared by every thread
+and every later payload of that configuration; shapes and dtypes are JAX's own
+jit cache, since (A, b) are arguments.
 
 Early-stop estimators (for ``RuntimeConfig.target_error``):
 
@@ -44,15 +47,22 @@ from repro.utils import prng
 
 # Host spans (``jax.profiler.TraceAnnotation``) each task writes into a profiler
 # trace when one is active: the task on its worker thread, and inside it the device
-# copy of A and b its payload makes once (enqueued, so the span counts copies; its
-# length is not the copy's).
+# copy of A and b its payload makes, if it needs one (enqueued, so the span counts
+# copies; its length is not the copy's), and the build of a task program this
+# process has not built yet (JAX traces and compiles it at its first call, so the
+# span counts builds; its length is not the build's).
 TASK_SPAN = "repro.task"
 UPLOAD_SPAN = "repro.task.upload"
+BUILD_SPAN = "repro.task.build"
 
-# Serialises the one-time upload + jit build of every payload in this process, so
-# threads racing on a new payload make one device copy of A and one program. A
-# module lock, not a field: task specs must stay picklable.
+# Serialises the one-time data set-up and program lookup of every payload in this
+# process, so threads racing on a new payload make at most one device copy of A and
+# one program. A module lock, not a field: task specs must stay picklable.
 _BUILD_LOCK = threading.Lock()
+
+# The jitted task programs built in this process, by static configuration
+# (``_PicklableCompute._config``). Programs only, never data: A and b are arguments.
+_PROGRAMS: dict = {}
 
 
 def _key_data(key) -> np.ndarray:
@@ -63,37 +73,66 @@ def _key_data(key) -> np.ndarray:
         return np.asarray(jax.random.key_data(key))
 
 
-class _PicklableCompute:
-    """Base for process-shippable payloads: numpy state + a lazily built jit.
+def _copy_target():
+    """The device a copy of a host array goes to: JAX's default device (a default
+    named only by its platform matches no array, so such a payload copies)."""
+    return jax.config.jax_default_device or jax.local_devices()[0]
 
-    The jit takes (A, b) as arguments, uploaded once per process: closed over,
-    they would be embedded in the program as constants (GBs at served sizes).
-    ``job`` is the id of the served job the payload belongs to (set by
-    ``SolveServer``; ``None`` otherwise), written on its host spans.
+
+def _resident(x, device) -> bool:
+    """``x`` is a ``jax.Array`` held whole on ``device`` alone."""
+    return isinstance(x, jax.Array) and x.is_fully_addressable and x.devices() == {device}
+
+
+class _PicklableCompute:
+    """Base for process-shippable payloads: (A, b) + a lazily found jit.
+
+    The jit takes (A, b) as arguments: closed over, they would be embedded in the
+    program as constants (GBs at served sizes). When A and b are both ``jax.Array``
+    values held whole on the device a copy would go to (JAX's default device), the
+    payload uses them as they are, with no copy. Otherwise (numpy, sharded, on
+    another device) it copies them to that device once per payload, at its first
+    task. Pickled, the payload carries A and b as numpy, so a process copies them
+    once on the far side. ``job`` is the id of the served job the payload belongs to
+    (set by ``SolveServer``; ``None`` otherwise), written on its host spans.
     """
 
     def __init__(self, spec: sk.SketchSpec, base_key, A, b):
         self.spec = spec
         self.base_key = _key_data(base_key)
-        self.A = np.asarray(A)
-        self.b = np.asarray(b)
+        self.A = A if isinstance(A, jax.Array) else np.asarray(A)
+        self.b = b if isinstance(b, jax.Array) else np.asarray(b)
         self.job: Optional[int] = None
         self._fn = None
         self._data = None
+
+    def _config(self) -> tuple:
+        """The static configuration the task program depends on: its cache key."""
+        return (type(self), self.spec)
 
     def _program(self) -> Callable:
         """The jitted task program ``(wkey, A, b) -> x̂``."""
         raise NotImplementedError
 
+    def _device_data(self):
+        """(A, b) on the copy target: the caller's arrays if already there, else a copy."""
+        target = _copy_target()
+        if _resident(self.A, target) and _resident(self.b, target):
+            return self.A, self.b
+        with jax.profiler.TraceAnnotation(UPLOAD_SPAN, job=self.job, bytes=self.A.nbytes + self.b.nbytes):
+            return jnp.asarray(np.asarray(self.A)), jnp.asarray(np.asarray(self.b))
+
     def _ready(self):
         if self._fn is None:
             with _BUILD_LOCK:
                 if self._fn is None:  # _fn is published last: a set _fn has its _data
-                    with jax.profiler.TraceAnnotation(
-                        UPLOAD_SPAN, job=self.job, bytes=self.A.nbytes + self.b.nbytes
-                    ):
-                        self._data = (jnp.asarray(self.A), jnp.asarray(self.b))
-                    self._fn = self._program()
+                    self._data = self._device_data()
+                    config = self._config()
+                    fn = _PROGRAMS.get(config)
+                    if fn is None:
+                        with jax.profiler.TraceAnnotation(BUILD_SPAN, job=self.job):
+                            fn = _PROGRAMS[config] = self._program()
+                    self._fn = fn
         return self._fn, self._data
 
     def _key(self, worker_id: int, round_id: int):
@@ -111,6 +150,7 @@ class _PicklableCompute:
 
     def __getstate__(self):
         state = dict(self.__dict__)
+        state["A"], state["b"] = np.asarray(self.A), np.asarray(self.b)  # numpy crosses processes
         state["_fn"] = None  # jit caches and device arrays never cross processes
         state["_data"] = None
         return state
@@ -123,6 +163,9 @@ class SketchSolveCompute(_PicklableCompute):
         super().__init__(spec, base_key, A, b)
         self.reg = float(reg)
         self.method = str(method)
+
+    def _config(self):
+        return (type(self), self.spec, self.reg, self.method)
 
     def _program(self):
         spec, reg, method = self.spec, self.reg, self.method

@@ -6,6 +6,7 @@ a killed worker surfaces as a ``drop`` event, re-enters deadline→backoff→ret
 with a fresh round-folded key, and innocent pool-mates (whose futures the broken
 pool also poisoned) are transparently re-run and never appear in the event log.
 """
+import contextlib
 import pickle
 import threading
 import time
@@ -109,6 +110,122 @@ def test_least_norm_compute_pickle_roundtrip():
     compute = rt.make_least_norm_compute(sk.SketchSpec("gaussian", 32), key, A, b)
     clone = pickle.loads(pickle.dumps(compute))
     np.testing.assert_array_equal(compute(2, 1), clone(2, 1))
+
+
+class _Spans:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names opened."""
+
+    def __init__(self):
+        self.names = []
+
+    def __call__(self, name, **_):
+        self.names.append(name)
+        return contextlib.nullcontext()
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    recorder = _Spans()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", recorder)
+    return recorder
+
+
+@contextlib.contextmanager
+def _build_events():
+    """JAX's trace, lowering and compile events recorded while the block runs."""
+    names = ("/jax/core/compile/jaxpr_trace_duration", "/jax/core/compile/jaxpr_to_mlir_module_duration",
+             "/jax/core/compile/backend_compile_duration")
+    seen = []
+
+    def listener(event, duration, **_):
+        if event in names:
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+def test_equal_configurations_build_one_program_between_them(spans):
+    """A second payload of the same (spec, reg, method) takes the program the first
+    built: one ``_program`` call, one build span, and its first program call neither
+    traces nor compiles. (Its worker key is derived eagerly, outside the program, by
+    ``jax.random`` primitives whose own caches JAX may evict and refill, so only the
+    program call is watched.)"""
+    key, A, b = _toy_problem()
+    builds = []
+
+    class Counting(rt.SketchSolveCompute):
+        def _program(self):
+            builds.append(self)
+            return super()._program()
+
+    spec = sk.SketchSpec("gaussian", 64)
+    first = Counting(spec, key, A, b, reg=0.5)
+    x_first = first(1, 0)
+    second = Counting(sk.SketchSpec("gaussian", 64), jax.random.PRNGKey(7), A, b, reg=0.5)
+    fn, data = second._ready()
+    wkey = second._key(1, 0)
+    with _build_events() as seen:
+        x_second = np.asarray(fn(wkey, *data))
+    assert seen == []
+    assert builds == [first] and spans.names.count(rt.tasks.BUILD_SPAN) == 1
+    assert fn is first._ready()[0]
+    np.testing.assert_array_equal(second(1, 0), x_second)
+    assert not np.array_equal(x_first, x_second)  # the second payload's own keys
+
+
+@pytest.mark.parametrize(
+    "changed",
+    [
+        {"reg": 0.5},
+        {"spec": sk.SketchSpec("gaussian", 32)},
+        {"spec": sk.SketchSpec("sjlt", 64, s=2)},
+        {"method": "qr"},
+    ],
+    ids=["reg", "spec_m", "spec_kind", "method"],
+)
+def test_other_configurations_get_their_own_program(changed):
+    """Payloads that differ in reg, spec or method get programs of their own, and each
+    answer equals a fresh, uncached build's bit for bit."""
+    key, A, b = _toy_problem()
+    base = {"spec": sk.SketchSpec("gaussian", 64), "reg": 0.0, "method": "fused"}
+    payloads = [rt.SketchSolveCompute(p.pop("spec"), key, A, b, **p) for p in (dict(base), {**base, **changed})]
+    assert payloads[0]._ready()[0] is not payloads[1]._ready()[0]
+    for p in payloads:
+        fresh = p._program()
+        for w in range(3):
+            np.testing.assert_array_equal(p(w, 0), np.asarray(fresh(p._key(w, 0), A, b)))
+
+
+def test_device_arrays_are_taken_as_they_are(spans):
+    """A payload given A and b on the default device makes no copy of them; one given
+    numpy arrays copies them once. Both answer with the same bits."""
+    key, A, b = _toy_problem()
+    spec = sk.SketchSpec("gaussian", 64)
+    resident = rt.SketchSolveCompute(spec, key, A, b)
+    xs = [resident(w, 0) for w in range(3)]
+    assert rt.tasks.UPLOAD_SPAN not in spans.names
+    assert resident._data[0] is A and resident._data[1] is b
+    host = rt.SketchSolveCompute(spec, key, np.asarray(A), np.asarray(b))
+    for w, x in enumerate(xs):
+        np.testing.assert_array_equal(host(w, 0), x)
+    assert spans.names.count(rt.tasks.UPLOAD_SPAN) == 1
+
+
+def test_payload_built_from_device_arrays_survives_pickle():
+    """Device arrays cross the pickle boundary as numpy; the clone copies them on its
+    side and answers bit for bit as the original does."""
+    key, A, b = _toy_problem()
+    compute = rt.SketchSolveCompute(sk.SketchSpec("gaussian", 64), key, A, b)
+    x = compute(1, 0)  # device data and program in place before the pickle
+    clone = pickle.loads(pickle.dumps(compute))
+    assert isinstance(clone.A, np.ndarray) and isinstance(clone.b, np.ndarray)
+    assert clone._fn is None and clone._data is None
+    np.testing.assert_array_equal(clone(1, 0), x)
+    np.testing.assert_array_equal(clone(0, 3), compute(0, 3))
 
 
 def test_kill_switch_refuses_to_kill_master():

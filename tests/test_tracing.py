@@ -9,6 +9,7 @@ from collections import defaultdict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import runtime as rt
@@ -61,14 +62,16 @@ def _trace_spans(path):
 
 
 def test_served_jobs_write_job_task_and_upload_spans(tmp_path):
+    """Job 1 gets (A, b) on the device and makes no copy; job 2 gets them as numpy and
+    copies them once. Neither builds: the warm-up job built the only program."""
     A, b = _data()
     server = SolveServer(latency=rt.ConstantLatency(0.01), config=rt.RuntimeConfig(deadline_s=10.0), backend="thread")
     spec = sk.SketchSpec("gaussian", M)
     server.submit_solve(A, b, spec, Q, seed=9)  # compiles the task program outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
-        for j in range(2):
-            server.submit_solve(A, b, spec, Q, seed=j)
+        server.submit_solve(A, b, spec, Q, seed=0)
+        server.submit_solve(np.asarray(A), np.asarray(b), spec, Q, seed=1)
     finally:
         jax.profiler.stop_trace()
     spans = _trace_spans(str(tmp_path))
@@ -84,9 +87,10 @@ def test_served_jobs_write_job_task_and_upload_spans(tmp_path):
         elif n == tasks.UPLOAD_SPAN:
             uploads[a["job"]] += 1
             assert int(a["bytes"]) == A.nbytes + b.nbytes
+        assert n != tasks.BUILD_SPAN
     for j in jobs:
         assert sorted(workers[j]) == sorted(str(w) for w in range(Q))
-        assert uploads[j] == 1
+    assert dict(uploads) == {"2": 1}
 
 
 def test_payload_job_id_defaults_to_none_and_survives_pickling():
